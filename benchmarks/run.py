@@ -943,6 +943,8 @@ TABLES = {"t1": t1_alpha_sweep, "t2": t2_heterogeneous, "t3": t3_num_clients,
 
 def main() -> None:
     from benchmarks.common import write_json
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="EXPERIMENTS.md budget (slow)")

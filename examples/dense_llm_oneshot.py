@@ -24,6 +24,7 @@ from repro.core.generator import tok_generator_init
 from repro.data import lm_batches, make_lm_data
 from repro.fl.protocol import param_bytes
 from repro.launch import steps as ST
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 
 VOCAB = 256
@@ -42,6 +43,7 @@ def train_client(arch: str, seed: int, steps: int = 40):
 
 
 def main():
+    enable_compile_cache()
     archs = ["llama3.2-3b", "qwen1.5-4b", "musicgen-large"]
     cfgs, params, up = [], [], 0
     for i, a in enumerate(archs):

@@ -17,9 +17,11 @@ from repro.configs.paper_cifar import smoke
 from repro.core import evaluate, train_dense_server
 from repro.data import make_classification_data
 from repro.fl import build_federation, fedavg
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     scfg = dataclasses.replace(
         smoke(), n_clients=3, client_kinds=("cnn1", "cnn2", "wrn16_1"),
         global_kind="wrn16_1", epochs=30, t_g=4, s_steps=6)

@@ -19,9 +19,11 @@ from repro.configs.paper_cifar import smoke
 from repro.core import evaluate, train_dense_server
 from repro.data import make_classification_data
 from repro.fl import CommLedger, build_federation, fedavg
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     scfg = dataclasses.replace(smoke(), epochs=80, t_g=5, s_steps=8)
     print(f"federation: {scfg.n_clients} clients, Dirichlet α={scfg.alpha}")
 

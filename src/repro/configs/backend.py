@@ -26,11 +26,12 @@ Backend detection precedence: ``scfg.backend`` > ``REPRO_BACKEND`` env
 > ``jax.default_backend()``. Interpret-mode: from the registry
 (cpu → True, gpu/tpu → False), overridable by ``REPRO_INTERPRET``
 ("1"/"0") — this also fixes the old ``_auto_interpret`` bug where a GPU
-backend silently ran every kernel in interpret mode. ``REPRO_AUTOTUNE=1``
-enables timing on cache miss; ``REPRO_AUTOTUNE_CACHE`` points the
-writable cache somewhere else (default ``~/.cache/repro-dense/
-autotune.json``). A committed seed cache (configs/autotune_seed.json)
-is always loaded first so CI timing noise never changes selected blocks.
+backend silently ran every kernel in interpret mode. A committed seed
+cache (configs/autotune_seed.json) is always loaded, so CI timing noise
+never changes selected blocks. ``REPRO_AUTOTUNE=1`` enables timing on
+cache miss and overlays the writable cache (``REPRO_AUTOTUNE_CACHE``,
+default ``~/.cache/repro-dense/autotune.json``); without it that file is
+never read, so the blocks a run uses come from committed files alone.
 """
 from __future__ import annotations
 
@@ -344,13 +345,15 @@ _cache_memo: dict = {}
 
 
 def _load_cache() -> dict:
-    """Seed cache overlaid by the writable cache, memoized per
-    (path, mtime) so resolution stays cheap at trace time."""
-    path = _default_cache_path()
-    sig = (path, _mtime(_SEED_CACHE), _mtime(path))
+    """Seed cache, overlaid by the writable cache only when autotuning is
+    on (``REPRO_AUTOTUNE=1``); memoized per (path, mtime) so resolution
+    stays cheap at trace time."""
+    path = _default_cache_path() if autotune_enabled() else None
+    sig = (path, _mtime(_SEED_CACHE), path and _mtime(path))
     if _cache_memo.get("sig") != sig:
         entries = _read_cache_file(_SEED_CACHE)
-        entries.update(_read_cache_file(path))
+        if path:
+            entries.update(_read_cache_file(path))
         _cache_memo.clear()
         _cache_memo["sig"] = sig
         _cache_memo["entries"] = entries
@@ -431,7 +434,7 @@ def resolve_exec_policy(scfg=None, *, backend=None) -> "ExecPolicy":
     b = backend or detect_backend(scfg)
     try:
         key = (b, scfg, os.environ.get("REPRO_INTERPRET"),
-               _cache_memo.get("sig"))
+               autotune_enabled(), _cache_memo.get("sig"))
         hash(key)
     except TypeError:
         key = None
@@ -617,7 +620,7 @@ def _candidate_runner(kernel, shape, blocks, interpret):
         r, d = 2, 16
         m = max(1, -(-int(t) // page))
         pool = jnp.linspace(-1.0, 1.0, (r * m + 1) * page * d,
-                            dtype=jnp.float32).reshape(r * m + 1, page, 1, d)
+                            dtype=jnp.float32).reshape(r * m + 1, 1, page, d)
         q = jnp.linspace(-1.0, 1.0, r * d,
                          dtype=jnp.float32).reshape(r, 1, d)
         bt = jnp.arange(r * m, dtype=jnp.int32).reshape(r, m) + 1

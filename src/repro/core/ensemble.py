@@ -282,8 +282,6 @@ def _group_sum_sharded(params, spec, x, size, mesh, with_stats,
     (size, ...) leading dim sharded over ``clients``). Callers guarantee
     divisibility (fl.sharding.group_shardable).
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.fl.sharding import CLIENT_AXIS, client_axis_size
 
     loc = size // client_axis_size(mesh)
@@ -300,8 +298,8 @@ def _group_sum_sharded(params, spec, x, size, mesh, with_stats,
         return (s, st) if with_stats else s
 
     out_specs = (P(), P(CLIENT_AXIS)) if with_stats else P()
-    out = shard_map(local, mesh=mesh, in_specs=(P(CLIENT_AXIS), P()),
-                    out_specs=out_specs, check_rep=False)(params, x)
+    out = jax.shard_map(local, mesh=mesh, in_specs=(P(CLIENT_AXIS), P()),
+                        out_specs=out_specs, check_vma=False)(params, x)
     return out if with_stats else (out, [])
 
 

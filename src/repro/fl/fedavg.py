@@ -103,7 +103,6 @@ def _tree_reduce_sharded(stacked, w, branch: int, mesh):
     then the cross-shard combine is a weighted psum pair — the mesh is
     the top level of the tree. Callers guarantee divisibility
     (fl.sharding.group_shardable)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.fl.sharding import CLIENT_AXIS
@@ -118,9 +117,9 @@ def _tree_reduce_sharded(stacked, w, branch: int, mesh):
             return (num / den).astype(leaf.dtype)
         return jax.tree.map(one, st)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(CLIENT_AXIS), P(CLIENT_AXIS)),
-                     out_specs=P(), check_rep=False)(stacked, w)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(CLIENT_AXIS), P(CLIENT_AXIS)),
+                         out_specs=P(), check_vma=False)(stacked, w)
 
 
 def fedavg_stacked(stacked_params, n_data, survivor_mask=None, *,

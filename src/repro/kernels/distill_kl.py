@@ -13,6 +13,9 @@ log-sum-exp accumulators for both distributions plus an online
 
 Accumulators live in revisited output blocks (index maps ignore the vocab
 grid axis), the TPU-idiomatic analogue of CUDA shared-memory reductions.
+Every per-row statistic travels as an (R, 1) column: a 1-D (R,) array is
+tiled by 1024 rows in XLA's TPU layout, which a (block_rows,) block of
+256 does not match, so the TPU compiler refuses 1-D row blocks.
 
 The *backward* is the repo's first custom-VJP kernel pair
 (``distill_kl_vjp``; DESIGN.md §9): the forward persists only its per-row
@@ -77,22 +80,24 @@ def _kl_fwd_kernel(t_ref, s_ref, kl_ref, mt_ref, zt_ref, st_ref, ms_ref,
     if mask_tail:
         t, s = _mask_cols(t, s, j, bv, vocab)
 
-    # online lse + weighted-diff for the teacher
+    # online lse + weighted-diff for the teacher; every per-row
+    # statistic is a (br, 1) column (the (R, 1) stats layout)
     mt_prev, zt_prev, st_prev = mt_ref[...], zt_ref[...], st_ref[...]
-    mt_cur = jnp.max(t, axis=1)
+    mt_cur = jnp.max(t, axis=1, keepdims=True)
     mt_new = jnp.maximum(mt_prev, mt_cur)
     at = jnp.exp(mt_prev - mt_new)
-    p = jnp.exp(t - mt_new[:, None])
-    zt_ref[...] = zt_prev * at + jnp.sum(p, axis=1)
-    st_ref[...] = st_prev * at + jnp.sum(p * (t - s), axis=1)
+    p = jnp.exp(t - mt_new)
+    zt_ref[...] = zt_prev * at + jnp.sum(p, axis=1, keepdims=True)
+    st_ref[...] = st_prev * at + jnp.sum(p * (t - s), axis=1, keepdims=True)
     mt_ref[...] = mt_new
 
     # online lse for the student
     ms_prev, zs_prev = ms_ref[...], zs_ref[...]
-    ms_cur = jnp.max(s, axis=1)
+    ms_cur = jnp.max(s, axis=1, keepdims=True)
     ms_new = jnp.maximum(ms_prev, ms_cur)
     as_ = jnp.exp(ms_prev - ms_new)
-    zs_ref[...] = zs_prev * as_ + jnp.sum(jnp.exp(s - ms_new[:, None]), axis=1)
+    zs_ref[...] = zs_prev * as_ + jnp.sum(jnp.exp(s - ms_new), axis=1,
+                                          keepdims=True)
     ms_ref[...] = ms_new
 
     @pl.when(j == nv - 1)
@@ -123,17 +128,18 @@ def distill_kl(teacher_logits, student_logits, *, block_rows: int,
     R, V = teacher_logits.shape
     br, bv, nr, nv, mask_tail = _blocking(R, V, block_rows, block_v)
 
-    row_map = lambda i, j: (i,)
-    kl, mt, zt, st, ms, zs = pl.pallas_call(
+    row_map = lambda i, j: (i, 0)
+    stats = pl.pallas_call(
         functools.partial(_kl_fwd_kernel, nv=nv, bv=bv, vocab=V,
                           mask_tail=mask_tail),
         grid=(nr, nv),
         in_specs=[pl.BlockSpec((br, bv), lambda i, j: (i, j)),
                   pl.BlockSpec((br, bv), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((br,), row_map)] * 6,
-        out_shape=[jax.ShapeDtypeStruct((R,), jnp.float32)] * 6,
+        out_specs=[pl.BlockSpec((br, 1), row_map)] * 6,
+        out_shape=[jax.ShapeDtypeStruct((R, 1), jnp.float32)] * 6,
         interpret=interpret,
     )(teacher_logits, student_logits)
+    kl, mt, zt, st, ms, zs = (a[:, 0] for a in stats)
     if return_stats:
         return kl, (mt, zt, st, ms, zs)
     return kl
@@ -153,16 +159,16 @@ def _kl_bwd_kernel(t_ref, s_ref, lt_ref, ls_ref, kl_ref, g_ref, *out_refs,
     s = s_ref[...].astype(jnp.float32)
     if mask_tail:
         t, s = _mask_cols(t, s, j, bv, vocab)
-    lt = lt_ref[...][:, None]            # lse_t, (br, 1)
-    ls = ls_ref[...][:, None]
-    g = g_ref[...][:, None]
+    lt = lt_ref[...]                     # lse_t, (br, 1)
+    ls = ls_ref[...]
+    g = g_ref[...]
     p = jnp.exp(t - lt)                  # softmax(t) block
     q = jnp.exp(s - ls)                  # softmax(s) block
     ds_ref = out_refs[-1]
     ds_ref[...] = (g * (q - p)).astype(ds_ref.dtype)
     if with_dt:
         dt_ref = out_refs[0]
-        kl = kl_ref[...][:, None]
+        kl = kl_ref[...]
         dt_ref[...] = (g * p * ((t - lt) - (s - ls) - kl)).astype(dt_ref.dtype)
 
 
@@ -175,7 +181,7 @@ def distill_kl_bwd(teacher_logits, student_logits, lse_t, lse_s, kl, g, *,
     R, V = teacher_logits.shape
     br, bv, nr, nv, mask_tail = _blocking(R, V, block_rows, block_v)
 
-    row_map = lambda i, j: (i,)
+    row_map = lambda i, j: (i, 0)
     blk_map = lambda i, j: (i, j)
     out_specs = [pl.BlockSpec((br, bv), blk_map)]
     out_shape = [jax.ShapeDtypeStruct((R, V), student_logits.dtype)]
@@ -189,11 +195,12 @@ def distill_kl_bwd(teacher_logits, student_logits, lse_t, lse_s, kl, g, *,
         grid=(nr, nv),
         in_specs=[pl.BlockSpec((br, bv), blk_map),
                   pl.BlockSpec((br, bv), blk_map)]
-        + [pl.BlockSpec((br,), row_map)] * 4,
+        + [pl.BlockSpec((br, 1), row_map)] * 4,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(teacher_logits, student_logits, lse_t, lse_s, kl, g)
+    )(teacher_logits, student_logits,
+      *(a.reshape(R, 1) for a in (lse_t, lse_s, kl, g)))
     if with_teacher_grad:
         return outs[0], outs[1]
     return None, outs[0]

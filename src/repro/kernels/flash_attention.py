@@ -117,9 +117,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
                        mask_q_tail=False, mask_k_tail=mask_k_tail)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[0]                                    # (bq,)
+    m_prev = m_ref[0]                                    # (bq, 1)
     l_prev = l_ref[0]
-    m_cur = jnp.max(s, axis=1)
+    m_cur = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
     # p under the mask, NOT exp(s - m_new): a fully-masked block (short
@@ -130,9 +130,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
     # but fatal to the persisted stats: l must be the exact live mass for
     # lse = m + log l to be the backward's softmax denominator, and
     # exactly 0 for never-live rows so their lse pins to NEG_INF
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1)
-    o_ref[0] = o_ref[0] * alpha[:, None] \
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    o_ref[0] = o_ref[0] * alpha \
         + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     m_ref[0] = m_new
@@ -140,7 +140,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)[:, None]
+        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)
 
 
 def _blocking(Sq: int, Sk: int, block_q: int, block_k: int):
@@ -166,7 +166,7 @@ def _fwd_flat(qf, kf, vf, *, Hq, Hkv, causal, window, scale, block_q,
         return ((bh // Hq) * Hkv + (bh % Hq) // g, j, 0)
 
     def ml_map(bh, i, j):
-        return (bh, i)
+        return (bh, i, 0)
 
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, bq=bq, bk=bk, nk=nk,
@@ -177,11 +177,11 @@ def _fwd_flat(qf, kf, vf, *, Hq, Hkv, causal, window, scale, block_q,
                   pl.BlockSpec((1, bk, D), kv_map),
                   pl.BlockSpec((1, bk, D), kv_map)],
         out_specs=[pl.BlockSpec((1, bq, D), q_map),
-                   pl.BlockSpec((1, bq), ml_map),
-                   pl.BlockSpec((1, bq), ml_map)],
+                   pl.BlockSpec((1, bq, 1), ml_map),
+                   pl.BlockSpec((1, bq, 1), ml_map)],
         out_shape=[jax.ShapeDtypeStruct((BH, Sq, D), jnp.float32),
-                   jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
-                   jax.ShapeDtypeStruct((BH, Sq), jnp.float32)],
+                   jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf)
 
@@ -214,6 +214,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         # fold (m, l) -> lse once per row; rows that never saw a live key
         # (l == 0) pin to NEG_INF so the backward's exp stays finite
         lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+        lse = lse[..., 0]
         return out.reshape(B, Hq, Sq, D).astype(q.dtype), out, lse
     return out.reshape(B, Hq, Sq, D).astype(q.dtype)
 
@@ -237,7 +238,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                     # (bq,)
+    lse = lse_ref[0]                                     # (bq, 1)
     delta = d_ref[0]
     if mask_k_tail:
         k = _zero_tail_rows(k, j, bk, sk)
@@ -248,10 +249,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
     mask = _block_mask(i, j, bq=bq, bk=bk, causal=causal, window=window,
                        seq_off=seq_off, sq=sq, sk=sk,
                        mask_q_tail=False, mask_k_tail=mask_k_tail)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     dq_ref[0] = dq_ref[0] + jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -284,7 +285,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
     if mask_q_tail:
         q = _zero_tail_rows(q, i, bq, sq)
         do = _zero_tail_rows(do, i, bq, sq)
-        row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq,), 0)
+        row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         lse = jnp.where(row < sq, lse, 0.0)
         delta = jnp.where(row < sq, delta, 0.0)
     if mask_k_tail:
@@ -296,13 +297,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
     mask = _block_mask(i, j, bq=bq, bk=bk, causal=causal, window=window,
                        seq_off=seq_off, sq=sq, sk=sk,
                        mask_q_tail=mask_q_tail, mask_k_tail=mask_k_tail)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dv_ref[0] = dv_ref[0] + jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     dk_ref[0] = dk_ref[0] + jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -328,7 +329,9 @@ def flash_attention_bwd(q, k, v, o_f32, lse, do, *, causal: bool = True,
     kf = k.reshape(B * Hkv, Sk, D)
     vf = v.reshape(B * Hkv, Sk, D)
     dof = do.astype(jnp.float32).reshape(B * Hq, Sq, D)
-    delta = jnp.sum(dof * o_f32, axis=-1)                # (B*Hq, Sq)
+    # per-row stats travel as (B*Hq, Sq, 1) columns (TPU block tiling)
+    delta = jnp.sum(dof * o_f32, axis=-1, keepdims=True)
+    lse = lse[..., None]
 
     def q_map(bh, i, j):
         return (bh, i, 0)
@@ -337,7 +340,7 @@ def flash_attention_bwd(q, k, v, o_f32, lse, do, *, causal: bool = True,
         return ((bh // Hq) * Hkv + (bh % Hq) // g, j, 0)
 
     def ml_map(bh, i, j):
-        return (bh, i)
+        return (bh, i, 0)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, bq=bq, bk=bk,
@@ -348,8 +351,8 @@ def flash_attention_bwd(q, k, v, o_f32, lse, do, *, causal: bool = True,
                   pl.BlockSpec((1, bk, D), kv_map),
                   pl.BlockSpec((1, bk, D), kv_map),
                   pl.BlockSpec((1, bq, D), q_map),
-                  pl.BlockSpec((1, bq), ml_map),
-                  pl.BlockSpec((1, bq), ml_map)],
+                  pl.BlockSpec((1, bq, 1), ml_map),
+                  pl.BlockSpec((1, bq, 1), ml_map)],
         out_specs=[pl.BlockSpec((1, bq, D), q_map)],
         out_shape=[jax.ShapeDtypeStruct((B * Hq, Sq, D), jnp.float32)],
         interpret=interpret,
@@ -365,7 +368,7 @@ def flash_attention_bwd(q, k, v, o_f32, lse, do, *, causal: bool = True,
         return (bh, j, 0)
 
     def mlt_map(bh, j, t):
-        return ((bh // Hkv) * Hq + (bh % Hkv) * g + t // nq, t % nq)
+        return ((bh // Hkv) * Hq + (bh % Hkv) * g + t // nq, t % nq, 0)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, bq=bq,
@@ -377,8 +380,8 @@ def flash_attention_bwd(q, k, v, o_f32, lse, do, *, causal: bool = True,
                   pl.BlockSpec((1, bk, D), kt_map),
                   pl.BlockSpec((1, bk, D), kt_map),
                   pl.BlockSpec((1, bq, D), qt_map),
-                  pl.BlockSpec((1, bq), mlt_map),
-                  pl.BlockSpec((1, bq), mlt_map)],
+                  pl.BlockSpec((1, bq, 1), mlt_map),
+                  pl.BlockSpec((1, bq, 1), mlt_map)],
         out_specs=[pl.BlockSpec((1, bk, D), kt_map),
                    pl.BlockSpec((1, bk, D), kt_map)],
         out_shape=[jax.ShapeDtypeStruct((B * Hkv, Sk, D), jnp.float32),

@@ -230,7 +230,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
                     scale=None, policy=None):
     """Decode attention through a block-pool cache (DESIGN.md §12).
 
-    q: (R, Hq, D); k/v_pool: (P, page, Hkv, D); block_tables: (R, M);
+    q: (R, Hq, D); k/v_pool: (P, Hkv, page, D); block_tables: (R, M);
     seq_lens: (R,). Routed by ``policy.kernel_vjp`` like the training
     kernels — ``"ref"`` runs the gather-then-materialize oracle,
     anything else the streaming Pallas kernel (forward-only by

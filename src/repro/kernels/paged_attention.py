@@ -14,6 +14,10 @@ House style (§9), adapted to decode:
     BlockSpec index maps read the block id from the scalar-prefetched
     table (``pltpu.PrefetchScalarGridSpec``), so the gather IS the
     pipeline's block fetch — no materialized (R, M*page, ...) copy.
+    The pool is head-major, ``(P, Hkv, page, D)``, so a fetched block is
+    a (page, D) tile: the TPU tiling rule wants the last two block dims
+    to be (8, 128)-aligned or whole, which a singleton head dim there
+    would break.
   * online (m, l) accumulators in revisited output blocks whose index
     maps ignore the innermost (table-slot) axis; init at ``j == 0``,
     finalize at ``j == M - 1``.
@@ -54,8 +58,8 @@ def _paged_kernel(seq_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                       # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)                    # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                       # (page, D)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -63,21 +67,21 @@ def _paged_kernel(seq_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     live = kpos < seq_ref[r]                                  # (1, page)
     s = jnp.where(live, s, NEG_INF)
 
-    m_prev = m_ref[0, 0]                                      # (G,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_ref[0, 0]                                      # (G, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     # p under the mask: a fully-dead block (slot past the live length, or
     # the null block of an inactive scheduler slot) must add zero mass,
     # not exp(NEG_INF - NEG_INF) = 1 per lane
-    p = jnp.where(live, jnp.exp(s - m_new[:, None]), 0.0)     # (G, page)
-    l_ref[0, 0] = l_ref[0, 0] * alpha + jnp.sum(p, axis=1)
-    o_ref[0, 0] = o_ref[0, 0] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)              # (G, page)
+    l_ref[0, 0] = l_ref[0, 0] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    o_ref[0, 0] = o_ref[0, 0] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[0, 0] = m_new
 
     @pl.when(j == nb - 1)
     def _finalize():
-        o_ref[0, 0] = o_ref[0, 0] / jnp.maximum(l_ref[0, 0], 1e-30)[:, None]
+        o_ref[0, 0] = o_ref[0, 0] / jnp.maximum(l_ref[0, 0], 1e-30)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
@@ -85,7 +89,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
     """Decode attention through a block table.
 
     q            : (R, Hq, D)   one incoming token per request slot
-    k/v_pool     : (P, page, Hkv, D) shared block pools (one layer)
+    k/v_pool     : (P, Hkv, page, D) shared block pools (one layer)
     block_tables : (R, M) int32 pool-block ids; slot ``j`` of request
                    ``r`` holds positions ``[j*page, (j+1)*page)``.
                    Unassigned entries must point at a real pool block
@@ -98,7 +102,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
     scheduler slots) produce exactly zero.
     """
     R, hq, d = q.shape
-    _, page, hkv, _ = k_pool.shape
+    _, hkv, page, _ = k_pool.shape
     m_slots = block_tables.shape[1]
     g = hq // hkv
     assert hq == hkv * g and v_pool.shape == k_pool.shape
@@ -109,12 +113,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
     # index maps receive the scalar-prefetch refs last and return BLOCK
     # indices; the k/v maps are the paging gather
     q_spec = pl.BlockSpec((1, 1, g, d), lambda r, h, j, seq, bt: (r, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, page, 1, d),
-                           lambda r, h, j, seq, bt: (bt[r, j], 0, h, 0))
+    kv_spec = pl.BlockSpec((1, 1, page, d),
+                           lambda r, h, j, seq, bt: (bt[r, j], h, 0, 0))
     acc_specs = [
         pl.BlockSpec((1, 1, g, d), lambda r, h, j, seq, bt: (r, h, 0, 0)),
-        pl.BlockSpec((1, 1, g), lambda r, h, j, seq, bt: (r, h, 0)),
-        pl.BlockSpec((1, 1, g), lambda r, h, j, seq, bt: (r, h, 0)),
+        pl.BlockSpec((1, 1, g, 1), lambda r, h, j, seq, bt: (r, h, 0, 0)),
+        pl.BlockSpec((1, 1, g, 1), lambda r, h, j, seq, bt: (r, h, 0, 0)),
     ]
     o, _, _ = pl.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale), page=page,
@@ -123,8 +127,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
             num_scalar_prefetch=2, grid=grid,
             in_specs=[q_spec, kv_spec, kv_spec], out_specs=acc_specs),
         out_shape=[jax.ShapeDtypeStruct((R, hkv, g, d), jnp.float32),
-                   jax.ShapeDtypeStruct((R, hkv, g), jnp.float32),
-                   jax.ShapeDtypeStruct((R, hkv, g), jnp.float32)],
+                   jax.ShapeDtypeStruct((R, hkv, g, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((R, hkv, g, 1), jnp.float32)],
         interpret=interpret,
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
       q.reshape(R, hkv, g, d), k_pool, v_pool)

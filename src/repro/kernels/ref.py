@@ -63,7 +63,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
                     scale=None):
     """Gather-then-materialize paged decode attention (the reference).
 
-    q: (R, Hq, D); k/v_pool: (P, page, Hkv, D); block_tables: (R, M);
+    q: (R, Hq, D); k/v_pool: (P, Hkv, page, D); block_tables: (R, M);
     seq_lens: (R,) live cached tokens per request. The oracle really
     gathers the whole (R, M*page) context per request and runs a
     materialized masked softmax — deliberately the opposite algorithm to
@@ -71,14 +71,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
     return exactly zero (matching the kernel's zero-mass finalize).
     """
     R, hq, d = q.shape
-    _, page, hkv, _ = k_pool.shape
+    _, hkv, page, _ = k_pool.shape
     m_slots = block_tables.shape[1]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
-    # (R, M, page, Hkv, D) -> (R, T, Hkv, D), T = M * page
-    k = k_pool[block_tables].reshape(R, m_slots * page, hkv, d)
-    v = v_pool[block_tables].reshape(R, m_slots * page, hkv, d)
+
+    def gather(pool):
+        # (R, M, Hkv, page, D) -> (R, T, Hkv, D), T = M * page
+        blocks = jnp.swapaxes(pool[block_tables], 2, 3)
+        return blocks.reshape(R, m_slots * page, hkv, d)
+
+    k, v = gather(k_pool), gather(v_pool)
     qg = q.reshape(R, hkv, g, d).astype(jnp.float32)
     scores = jnp.einsum("rkgd,rtkd->rkgt", qg,
                         k.astype(jnp.float32)) * scale

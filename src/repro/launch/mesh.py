@@ -25,12 +25,9 @@ ICI_BW = 50e9                   # bytes/s per link
 
 
 def axis_types_kw(n_axes: int) -> dict:
-    """{"axis_types": (Auto,)*n} on jax versions that have AxisType
-    (>=0.5), {} on older ones where Auto is the only behaviour anyway."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return {}
-    return {"axis_types": (at.Auto,) * n_axes}
+    """``jax.make_mesh`` kwargs for an all-Auto mesh: shardings propagate
+    through the program (GSPMD) instead of being explicit in types."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

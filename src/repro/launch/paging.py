@@ -4,10 +4,11 @@
 fixed request batch — the serving engine instead draws from a shared
 pool sized once at startup:
 
-  * **KV pool** — per attention layer stack, ``(L, P, page, Kh, Dh)``:
-    ``P`` fixed-size blocks of ``page`` tokens each. Position ``t`` of
-    the request in scheduler slot ``r`` lives at
-    ``(block_tables[r, t // page], t % page)``.
+  * **KV pool** — per attention layer stack, ``(L, P, Kh, page, Dh)``:
+    ``P`` fixed-size blocks of ``page`` tokens each, head-major so the
+    decode kernel fetches one (page, Dh) tile per (block, kv head).
+    Position ``t`` of the request in scheduler slot ``r`` lives at
+    ``(block_tables[r, t // page], :, t % page)``.
   * **block tables** — ``(max_reqs, M)`` int32, ``M = ceil(max_len /
     page)``; unassigned entries stay 0.
   * **SSM slots** — mamba2 decode state is O(1) per request, so it is
@@ -101,8 +102,8 @@ class BlockAllocator:
 
 def init_paged_cache(cfg, *, max_reqs: int, n_blocks: int, page: int):
     """The pool tree. Mirrors ``T.init_cache``'s per-family structure,
-    with every attention cache's dense ``(B, T, ...)`` axes replaced by
-    pool ``(P, page, ...)`` axes and every SSM state's batch axis sized
+    with every attention cache's dense ``(B, T, Kh, Dh)`` axes replaced
+    by pool ``(P, Kh, page, Dh)`` axes and every SSM state's batch axis sized
     to ``max_reqs`` slots. Zeros throughout — so unwritten pool rows are
     finite and the kernel's masked lanes multiply against real numbers.
     """
@@ -116,7 +117,7 @@ def init_paged_cache(cfg, *, max_reqs: int, n_blocks: int, page: int):
     fam = cfg.family
 
     def kv_pool(n):
-        shape = (n, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+        shape = (n, n_blocks, cfg.n_kv_heads, page, cfg.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def ssm_slots(lead):
@@ -142,8 +143,8 @@ def init_paged_cache(cfg, *, max_reqs: int, n_blocks: int, page: int):
 
 def _scatter_kv(pool, cache, row):
     """Dense prefill KV ``(L, 1, p, Kh, Dh)`` -> pool blocks ``row[:nb]``
-    of ``(L, P, page, Kh, Dh)`` (tail of the last block left as zeros)."""
-    page = pool["k"].shape[2]
+    of ``(L, P, Kh, page, Dh)`` (tail of the last block left as zeros)."""
+    page = pool["k"].shape[3]
     p = cache["k"].shape[2]
     nb = -(-p // page)
     pad = nb * page - p
@@ -152,6 +153,7 @@ def _scatter_kv(pool, cache, row):
         c = cache[n][:, 0]                              # (L, p, Kh, Dh)
         c = jnp.pad(c, ((0, 0), (0, pad), (0, 0), (0, 0)))
         c = c.reshape(c.shape[0], nb, page, *c.shape[2:])
+        c = jnp.swapaxes(c, 2, 3)                       # (L, nb, Kh, page, Dh)
         out[n] = pool[n].at[:, row[:nb]].set(c.astype(pool[n].dtype))
     return out
 

@@ -55,6 +55,8 @@ def serve(arch: str, *, batch: int, prompt_len: int, gen: int,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--batch", type=int, default=4)

@@ -65,6 +65,8 @@ def train(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)

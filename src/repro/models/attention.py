@@ -216,11 +216,11 @@ def gqa_apply_paged(p: dict, x: jnp.ndarray, cfg: ArchConfig, *,
 
     x: (R, 1, D) — the incoming token for each scheduler slot;
     positions: (R,) int32 — that token's absolute position (== tokens
-    already cached for the slot); pool: {"k","v"} of (P, page, Kh, Dh);
+    already cached for the slot); pool: {"k","v"} of (P, Kh, page, Dh);
     block_tables: (R, M).
 
-    The new K/V is scattered to pool row ``(block_tables[r, pos//page],
-    pos % page)`` — inactive slots carry all-zero table rows, so their
+    The new K/V is scattered to pool rows ``(block_tables[r, pos//page],
+    :, pos % page)`` — inactive slots carry all-zero table rows, so their
     writes land in reserved null block 0 — then attention runs over each
     slot's first ``positions[r] + 1`` cached tokens through
     ops.paged_attention (policy-routed: ref oracle or Pallas kernel).
@@ -236,15 +236,13 @@ def gqa_apply_paged(p: dict, x: jnp.ndarray, cfg: ArchConfig, *,
     q = L.apply_rope(q, cos, sin)                    # per-request (R,1,half)
     k = L.apply_rope(k, cos, sin)
 
-    P, page = pool["k"].shape[0], pool["k"].shape[1]
+    page = pool["k"].shape[2]
     blk = jnp.take_along_axis(block_tables,
                               (positions // page)[:, None], axis=1)[:, 0]
-    flat = blk * page + positions % page             # (R,) pool row ids
-    new_pool = {}
-    for name, cur in (("k", k), ("v", v)):
-        fp = pool[name].reshape(P * page, kh, hd)
-        new_pool[name] = fp.at[flat].set(
-            cur[:, 0].astype(fp.dtype)).reshape(P, page, kh, hd)
+    off = positions % page                           # (R,) in-block offset
+    new_pool = {name: pool[name].at[blk, :, off].set(
+                    cur[:, 0].astype(pool[name].dtype))
+                for name, cur in (("k", k), ("v", v))}
 
     from repro.kernels import ops as kops
     out = kops.paged_attention(q[:, 0], new_pool["k"], new_pool["v"],

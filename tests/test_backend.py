@@ -210,7 +210,8 @@ def test_autotune_disabled_returns_registry(monkeypatch):
     assert not os.path.exists(B._default_cache_path())
 
 
-def test_corrupt_cache_warns_and_falls_back(tmp_path):
+def test_corrupt_cache_warns_and_falls_back(monkeypatch, tmp_path):
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")   # the file is read only then
     path = tmp_path / "autotune.json"
     path.write_text("{not json")
     B.clear_caches()
@@ -219,13 +220,27 @@ def test_corrupt_cache_warns_and_falls_back(tmp_path):
     assert pol.blocks_for("distill_kl") == (256, 2048)
 
 
-def test_stale_cache_version_warns_and_falls_back(tmp_path):
+def test_stale_cache_version_warns_and_falls_back(monkeypatch, tmp_path):
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
     path = tmp_path / "autotune.json"
     path.write_text(json.dumps({"version": 99, "entries": {}}))
     B.clear_caches()
     with pytest.warns(UserWarning, match="unreadable autotune cache"):
         pol = B.resolve_exec_policy(None, backend="cpu")
     assert pol.blocks_for("ssd_scan") == (128,)
+
+
+def test_writable_cache_read_only_when_autotuning(monkeypatch, tmp_path):
+    """Without REPRO_AUTOTUNE=1 the blocks come from committed files
+    alone: the writable cache outside the checkout is never read."""
+    _write_cache(tmp_path / "autotune.json",
+                 {"cpu/distill_kl/64x128":
+                  {"blocks": {"block_rows": 32, "block_v": 64}, "us": 1.0}})
+    pol = B.resolve_exec_policy(None, backend="cpu")
+    assert pol.blocks_for("distill_kl", (40, 100)) == (256, 2048)
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
+    pol = B.resolve_exec_policy(None, backend="cpu")
+    assert pol.blocks_for("distill_kl", (40, 100)) == (32, 64)
 
 
 def test_deterministic_winner_under_ties(monkeypatch, tmp_path):

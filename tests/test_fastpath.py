@@ -123,7 +123,16 @@ def test_chunk_bounds():
 def test_fused_and_python_drivers_agree():
     """loop_mode='fused' must be a pure perf choice: same student params,
     same metric history as the per-step python driver for the same key
-    (both consume the identical per-epoch key stream)."""
+    (both consume the identical per-epoch key stream).
+
+    Same math, not the same bits: the fused driver compiles a whole chunk
+    of epochs as one program, the python driver one program per step, and
+    XLA fuses and orders the reductions of the two differently. Epoch 0
+    therefore agrees to about one ulp — a key-stream or ordering bug
+    would miss by orders of magnitude more. Later epochs drift further:
+    the generator's Adam step divides by sqrt(v) and amplifies a rounding
+    difference in a near-zero gradient, and that reaches the student
+    through the synthetic batch (DESIGN.md §11)."""
     clients = []
     sp = CNNSpec(kind="cnn1", num_classes=SCFG.num_classes, in_ch=SCFG.in_ch,
                  width=SCFG.width, image_size=SCFG.image_size)
@@ -138,9 +147,13 @@ def test_fused_and_python_drivers_agree():
         outs[mode] = (stu, gen, hist)
     stu_p, _, hist_p = outs["python"]
     stu_f, _, hist_f = outs["fused"]
-    for a, b in zip(jax.tree.leaves(stu_p), jax.tree.leaves(stu_f)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
     assert len(hist_f.gen_loss) == len(hist_p.gen_loss) == SCFG.epochs
+    np.testing.assert_allclose(hist_f.gen_loss[0], hist_p.gen_loss[0],
+                               rtol=1e-6)
+    np.testing.assert_allclose(hist_f.dis_loss[0], hist_p.dis_loss[0],
+                               rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(stu_p), jax.tree.leaves(stu_f)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
     np.testing.assert_allclose(hist_f.gen_loss, hist_p.gen_loss, rtol=1e-3,
                                atol=1e-5)
     np.testing.assert_allclose(hist_f.dis_loss, hist_p.dis_loss, rtol=1e-3,

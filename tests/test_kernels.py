@@ -1,7 +1,10 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles (ref.py).
 
-Kernels run in interpret mode on CPU (the TPU lowering is exercised by the
-same pallas_call).
+Kernels run in interpret mode on CPU. That checks the numerics only:
+interpret mode never applies the TPU's block-tiling rule or runs its
+kernel compiler, so it passes kernels the chip would refuse. The TPU
+lowering is exercised by tests/test_tpu_compile.py, which compiles each
+kernel for a described v5e.
 
 The custom-VJP suites (distill_kl, flash_attention, ssd_scan — the §9
 kernel pairs) double as CI's ``kernel-grads`` matrix: ``KERNEL_GRAD_DTYPE``

@@ -201,8 +201,8 @@ def test_paged_kernel_matches_oracle_ragged(page, m, seqs):
     ks = jax.random.split(jax.random.PRNGKey(17), 3)
     n_blocks = 1 + r * m
     q = jax.random.normal(ks[0], (r, hq, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (n_blocks, page, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (n_blocks, page, hkv, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (n_blocks, hkv, page, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (n_blocks, hkv, page, d), jnp.float32)
     bt = (jnp.arange(r * m, dtype=jnp.int32) + 1).reshape(r, m)
     seq = jnp.asarray(seqs, jnp.int32)
     out = PK.paged_attention(q, kp, vp, bt, seq, interpret=True)
@@ -217,7 +217,7 @@ def test_paged_kernel_null_row_is_zero_mass():
     garbage."""
     page, m = 8, 2
     q = jnp.ones((2, 2, 8), jnp.float32)
-    pool = jnp.full((5, page, 1, 8), 7.5, jnp.float32)
+    pool = jnp.full((5, 1, page, 8), 7.5, jnp.float32)
     bt = jnp.asarray([[0, 0], [1, 2]], jnp.int32)
     seq = jnp.asarray([0, 5], jnp.int32)
     out = PK.paged_attention(q, pool, pool, bt, seq, interpret=True)
@@ -232,7 +232,7 @@ def test_ops_paged_attention_policy_routing():
     from repro.kernels import ops
     pol = B.resolve_exec_policy(None)
     q = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 16))
-    pool = jax.random.normal(jax.random.PRNGKey(2), (5, 8, 2, 16))
+    pool = jax.random.normal(jax.random.PRNGKey(2), (5, 2, 8, 16))
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     seq = jnp.asarray([5, 11], jnp.int32)
     a = ops.paged_attention(q, pool, pool, bt, seq,
