@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.backend import resolve_exec_policy
 from repro.core import generator as G
 from repro.core import losses as LS
@@ -132,52 +133,67 @@ def make_dense_steps(clients: Sequence[Client], student_spec: CNNSpec,
     @jax.jit
     def gen_step(gen_p, g_state, stu_p, gparams, z, y):
         def loss_fn(gp):
-            x = gen_forward(gp, z)
-            avg, stats = grouped_ensemble_logits(gspecs, gparams, x,
-                                                 with_bn_stats=True,
-                                                 mesh=mesh, chunk=t_chunk)
-            stu = cnn_logits(stu_p, student_spec, x)
-            l_ce = LS.ce_loss(avg, y)
-            l_bn = LS.bn_loss(stats) if use_bn else jnp.zeros(())
-            l_div = LS.div_loss(avg, stu, mode=kl_mode, policy=pol) \
-                if use_div else jnp.zeros(())
-            total = l_ce + scfg.lambda_bn * l_bn + scfg.lambda_div * l_div
+            with jax.named_scope(obs.GENERATOR):
+                x = gen_forward(gp, z)
+            with jax.named_scope(obs.TEACHER):
+                avg, stats = grouped_ensemble_logits(
+                    gspecs, gparams, x, with_bn_stats=True, mesh=mesh,
+                    chunk=t_chunk)
+            with jax.named_scope(obs.STUDENT):
+                stu = cnn_logits(stu_p, student_spec, x)
+            with jax.named_scope(obs.LOSS):
+                l_ce = LS.ce_loss(avg, y)
+                l_bn = LS.bn_loss(stats) if use_bn else jnp.zeros(())
+                l_div = LS.div_loss(avg, stu, mode=kl_mode, policy=pol) \
+                    if use_div else jnp.zeros(())
+                total = l_ce + scfg.lambda_bn * l_bn \
+                    + scfg.lambda_div * l_div
             return total, {"ce": l_ce, "bn": l_bn, "div": l_div}
 
         (loss, parts), grads = jax.value_and_grad(loss_fn, has_aux=True)(gen_p)
-        new_p, new_state = g_opt.update(grads, g_state, gen_p)
-        if nan_guard:
-            ok = jnp.isfinite(loss) & jnp.isfinite(optim.global_norm(grads))
-            new_p = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                 new_p, gen_p)
-            new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                     new_state, g_state)
+        with jax.named_scope(obs.GENERATOR):
+            new_p, new_state = g_opt.update(grads, g_state, gen_p)
+            if nan_guard:
+                ok = jnp.isfinite(loss) & \
+                    jnp.isfinite(optim.global_norm(grads))
+                new_p = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                     new_p, gen_p)
+                new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                         new_state, g_state)
         return new_p, new_state, loss, parts
 
     @jax.jit
     def student_step(stu_p, s_state, gen_p, gparams, z):
-        x = jax.lax.stop_gradient(gen_forward(gen_p, z))
-        avg = grouped_ensemble_logits(gspecs, gparams, x, mesh=mesh,
-                                      chunk=t_chunk)
+        with jax.named_scope(obs.GENERATOR):
+            x = jax.lax.stop_gradient(gen_forward(gen_p, z))
+        with jax.named_scope(obs.TEACHER):
+            avg = grouped_ensemble_logits(gspecs, gparams, x, mesh=mesh,
+                                          chunk=t_chunk)
 
         def loss_fn(sp):
-            logits, new_sp, _ = cnn_apply(sp, student_spec, x, train=True)
-            # avg is stop-gradient'd upstream: skip the fused dL/dt stream
-            return LS.distill_loss(avg, logits, mode=kl_mode,
-                                   with_teacher_grad=False,
-                                   policy=pol), new_sp
+            with jax.named_scope(obs.STUDENT):
+                logits, new_sp, _ = cnn_apply(sp, student_spec, x,
+                                              train=True)
+            with jax.named_scope(obs.LOSS):
+                # avg is stop-gradient'd upstream: skip the fused dL/dt
+                # stream
+                loss = LS.distill_loss(avg, logits, mode=kl_mode,
+                                       with_teacher_grad=False, policy=pol)
+            return loss, new_sp
 
         (loss, stats_p), grads = jax.value_and_grad(loss_fn, has_aux=True)(stu_p)
-        new_p, new_state = s_opt.update(grads, s_state, stu_p)
-        new_p = merge_bn_stats(new_p, stats_p)
-        if nan_guard:
-            # guards the merged BN stats too: a non-finite synthetic
-            # batch would otherwise poison the running mean/var
-            ok = jnp.isfinite(loss) & jnp.isfinite(optim.global_norm(grads))
-            new_p = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                 new_p, stu_p)
-            new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                     new_state, s_state)
+        with jax.named_scope(obs.STUDENT):
+            new_p, new_state = s_opt.update(grads, s_state, stu_p)
+            new_p = merge_bn_stats(new_p, stats_p)
+            if nan_guard:
+                # guards the merged BN stats too: a non-finite synthetic
+                # batch would otherwise poison the running mean/var
+                ok = jnp.isfinite(loss) & \
+                    jnp.isfinite(optim.global_norm(grads))
+                new_p = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                     new_p, stu_p)
+                new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                         new_state, s_state)
         return new_p, new_state, loss
 
     t_g = scfg.t_g
@@ -315,38 +331,42 @@ def train_dense_server(key, clients: Sequence[Client], scfg,
     if nan_policy not in ("raise", "skip", "rollback"):
         raise ValueError(f"unknown nan_policy {nan_policy!r} "
                          "(expected 'raise', 'skip' or 'rollback')")
-    k_gen, k_stu, key = jax.random.split(key, 3)
-    gen_p = G.img_generator_init(k_gen, nz=scfg.nz, img_size=scfg.image_size,
-                                 out_ch=scfg.in_ch)
-    stu_p = student_params if student_params is not None \
-        else cnn_init(k_stu, student_spec)
-
-    (gen_step, student_step, g_opt, s_opt, gparams, epoch_step,
-     epochs_step) = make_dense_steps(clients, student_spec, scfg,
-                                     use_bn=use_bn, use_div=use_div)
-    g_state = g_opt.init(gen_p)
-    s_state = s_opt.init(stu_p)
-
     ck_every = int(getattr(scfg, "checkpoint_every", 0) or 0)
     ck_path = getattr(scfg, "checkpoint_path", "") or ""
     ckpt_on = bool(ck_every and ck_path)
     start_epoch = 0
-    if ckpt_on and checkpoint_exists(ck_path):
-        like = {"gen_p": gen_p, "g_state": g_state, "stu_p": stu_p,
-                "s_state": s_state, "key": key,
-                "epoch": np.zeros((), np.int64)}
-        st = restore_checkpoint(ck_path, like)
-        gen_p, g_state = st["gen_p"], st["g_state"]
-        stu_p, s_state = st["stu_p"], st["s_state"]
-        key, start_epoch = st["key"], int(st["epoch"])
+    with obs.span("dense.setup"):
+        with obs.span("dense.make_steps"):
+            (gen_step, student_step, g_opt, s_opt, gparams, epoch_step,
+             epochs_step) = make_dense_steps(clients, student_spec, scfg,
+                                             use_bn=use_bn, use_div=use_div)
+        with obs.span("dense.init"):
+            k_gen, k_stu, key = jax.random.split(key, 3)
+            gen_p = G.img_generator_init(k_gen, nz=scfg.nz,
+                                         img_size=scfg.image_size,
+                                         out_ch=scfg.in_ch)
+            stu_p = student_params if student_params is not None \
+                else cnn_init(k_stu, student_spec)
+            g_state = g_opt.init(gen_p)
+            s_state = s_opt.init(stu_p)
+        if ckpt_on and checkpoint_exists(ck_path):
+            with obs.span("dense.restore"):
+                like = {"gen_p": gen_p, "g_state": g_state, "stu_p": stu_p,
+                        "s_state": s_state, "key": key,
+                        "epoch": np.zeros((), np.int64)}
+                st = restore_checkpoint(ck_path, like)
+                gen_p, g_state = st["gen_p"], st["g_state"]
+                stu_p, s_state = st["stu_p"], st["s_state"]
+                key, start_epoch = st["key"], int(st["epoch"])
 
     def save_ckpt(gp, gs, sp, ss, epoch_done):
-        save_checkpoint(ck_path,
-                        {"gen_p": gp, "g_state": gs, "stu_p": sp,
-                         "s_state": ss, "key": key,
-                         "epoch": np.asarray(epoch_done, np.int64)},
-                        meta={"epoch": int(epoch_done),
-                              "epochs": int(scfg.epochs)})
+        with obs.span("dense.checkpoint"):
+            save_checkpoint(ck_path,
+                            {"gen_p": gp, "g_state": gs, "stu_p": sp,
+                             "s_state": ss, "key": key,
+                             "epoch": np.asarray(epoch_done, np.int64)},
+                            meta={"epoch": int(epoch_done),
+                                  "epochs": int(scfg.epochs)})
 
     hist = DenseHistory()
     s_steps = getattr(scfg, "s_steps", 1)
@@ -361,7 +381,8 @@ def train_dense_server(key, clients: Sequence[Client], scfg,
     def maybe_eval(epoch_done):
         if eval_fn is not None and eval_every and \
                 epoch_done % eval_every == 0:
-            hist.acc.append((epoch_done, eval_fn(stu_p, student_spec)))
+            with obs.span("dense.eval"):
+                hist.acc.append((epoch_done, eval_fn(stu_p, student_spec)))
 
     def check_finite(gl, dl, where):
         bad = not (np.all(np.isfinite(gl)) and np.all(np.isfinite(dl)))
@@ -376,62 +397,78 @@ def train_dense_server(key, clients: Sequence[Client], scfg,
         for lo, hi in _chunk_bounds(scfg.epochs, loop_chunk, eval_every,
                                     ck_every if ckpt_on else 0,
                                     start_epoch):
-            if nan_policy == "rollback":
-                # epochs_step donates its carries — snapshot copies
-                snap = jax.tree.map(jnp.copy,
-                                    (gen_p, g_state, stu_p, s_state))
-            gen_p, g_state, stu_p, s_state, metrics = epochs_step(
-                gen_p, g_state, stu_p, s_state, gparams, epoch_keys[lo:hi])
-            m = jax.device_get(metrics)      # ONE host sync per chunk
-            hist.gen_loss.extend(float(v) for v in m["gen_loss"])
-            hist.dis_loss.extend(float(v) for v in m["dis_loss"])
-            hist.gen_parts.extend(
-                {k: float(v[i]) for k, v in m["parts"].items()}
-                for i in range(hi - lo))
-            bad = check_finite(m["gen_loss"], m["dis_loss"],
-                               f"epochs [{lo}, {hi})")
-            if bad and nan_policy == "rollback":
-                gen_p, g_state, stu_p, s_state = snap
-            maybe_eval(hi)
-            if _stop_after_epoch and hi >= _stop_after_epoch:
-                return stu_p, gen_p, hist    # simulated kill beats save
-            if ckpt_on and hi % ck_every == 0:
-                save_ckpt(gen_p, g_state, stu_p, s_state, hi)
+            with obs.span("dense.chunk", lo=lo, hi=hi):
+                if nan_policy == "rollback":
+                    # epochs_step donates its carries — snapshot copies
+                    snap = jax.tree.map(jnp.copy,
+                                        (gen_p, g_state, stu_p, s_state))
+                args = (gen_p, g_state, stu_p, s_state, gparams,
+                        epoch_keys[lo:hi])
+                obs.keep_program("dense.epochs_step", epochs_step, *args)
+                with obs.span("dense.dispatch"):
+                    gen_p, g_state, stu_p, s_state, metrics = epochs_step(
+                        *args)
+                with obs.span("dense.sync"):
+                    m = jax.device_get(metrics)  # ONE host sync per chunk
+                obs.count("dense.host_syncs")
+                with obs.span("dense.history"):
+                    hist.gen_loss.extend(float(v) for v in m["gen_loss"])
+                    hist.dis_loss.extend(float(v) for v in m["dis_loss"])
+                    hist.gen_parts.extend(
+                        {k: float(v[i]) for k, v in m["parts"].items()}
+                        for i in range(hi - lo))
+                    bad = check_finite(m["gen_loss"], m["dis_loss"],
+                                       f"epochs [{lo}, {hi})")
+                obs.count("dense.chunks")
+                obs.count("dense.epochs", hi - lo)
+                if bad and nan_policy == "rollback":
+                    obs.count("dense.rollbacks")
+                    gen_p, g_state, stu_p, s_state = snap
+                maybe_eval(hi)
+                if _stop_after_epoch and hi >= _stop_after_epoch:
+                    return stu_p, gen_p, hist  # simulated kill beats save
+                if ckpt_on and hi % ck_every == 0:
+                    save_ckpt(gen_p, g_state, stu_p, s_state, hi)
     elif loop_mode == "python":
         b, nz = scfg.synth_batch, scfg.nz
         snap = (gen_p, g_state, stu_p, s_state)
         for epoch in range(start_epoch, scfg.epochs):
-            # identical derivation to _epoch_body
-            kz, ky, ks = jax.random.split(epoch_keys[epoch], 3)
-            z = jax.random.normal(kz, (b, nz))
-            if epoch in poison:
-                z = jnp.full_like(z, jnp.nan)
-            y = jax.random.randint(ky, (b,), 0, scfg.num_classes)
-            for _ in range(scfg.t_g):
-                gen_p, g_state, gl, parts = gen_step(gen_p, g_state, stu_p,
-                                                     gparams, z, y)
-            stu_p, s_state, dl = student_step(stu_p, s_state, gen_p,
-                                              gparams, z)
-            if s_steps > 1:
-                extra = jax.random.normal(ks, (s_steps - 1, b, nz))
-                for j in range(s_steps - 1):
-                    stu_p, s_state, dl = student_step(stu_p, s_state, gen_p,
-                                                      gparams, extra[j])
-            hist.gen_loss.append(float(gl))
-            hist.gen_parts.append({k: float(v) for k, v in parts.items()})
-            hist.dis_loss.append(float(dl))
-            bad = check_finite(hist.gen_loss[-1], hist.dis_loss[-1],
-                               f"epoch {epoch}")
-            if nan_policy == "rollback":
-                if bad:
-                    gen_p, g_state, stu_p, s_state = snap
-                else:
-                    snap = (gen_p, g_state, stu_p, s_state)
-            maybe_eval(epoch + 1)
-            if _stop_after_epoch and epoch + 1 >= _stop_after_epoch:
-                return stu_p, gen_p, hist    # simulated kill beats save
-            if ckpt_on and (epoch + 1) % ck_every == 0:
-                save_ckpt(gen_p, g_state, stu_p, s_state, epoch + 1)
+            with obs.span("dense.epoch", epoch=epoch):
+                # identical derivation to _epoch_body
+                kz, ky, ks = jax.random.split(epoch_keys[epoch], 3)
+                z = jax.random.normal(kz, (b, nz))
+                if epoch in poison:
+                    z = jnp.full_like(z, jnp.nan)
+                y = jax.random.randint(ky, (b,), 0, scfg.num_classes)
+                for _ in range(scfg.t_g):
+                    gen_p, g_state, gl, parts = gen_step(
+                        gen_p, g_state, stu_p, gparams, z, y)
+                stu_p, s_state, dl = student_step(stu_p, s_state, gen_p,
+                                                  gparams, z)
+                if s_steps > 1:
+                    extra = jax.random.normal(ks, (s_steps - 1, b, nz))
+                    for j in range(s_steps - 1):
+                        stu_p, s_state, dl = student_step(
+                            stu_p, s_state, gen_p, gparams, extra[j])
+                hist.gen_loss.append(float(gl))
+                hist.gen_parts.append({k: float(v) for k, v in parts.items()})
+                hist.dis_loss.append(float(dl))
+                # one transfer per fetched scalar: both losses and the parts
+                obs.count("dense.host_syncs", 2 + len(parts))
+                obs.count("dense.epochs")
+                bad = check_finite(hist.gen_loss[-1], hist.dis_loss[-1],
+                                   f"epoch {epoch}")
+                if nan_policy == "rollback":
+                    if bad:
+                        obs.count("dense.rollbacks")
+                        gen_p, g_state, stu_p, s_state = snap
+                    else:
+                        snap = (gen_p, g_state, stu_p, s_state)
+                maybe_eval(epoch + 1)
+                if _stop_after_epoch and epoch + 1 >= _stop_after_epoch:
+                    return stu_p, gen_p, hist  # simulated kill beats save
+                if ckpt_on and (epoch + 1) % ck_every == 0:
+                    save_ckpt(gen_p, g_state, stu_p, s_state, epoch + 1)
     else:
         raise ValueError(f"unknown loop_mode {loop_mode!r} "
                          "(expected 'python' or 'fused')")

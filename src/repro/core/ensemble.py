@@ -342,27 +342,30 @@ def grouped_ensemble_logits(gspecs, gparams, x: jnp.ndarray, *,
     logits_sum = None
     all_stats = []
     for (spec, size), params in zip(gspecs, gparams):
-        if size == 1:
-            lg, _, stats = cnn_apply(params, spec, x, train=False)
-            group_sum = lg.astype(jnp.float32)
-            if with_bn_stats:
-                all_stats.append(stats)
-        else:
-            if mesh is not None and group_shardable(mesh, size):
-                group_sum, stacked_stats = _group_sum_sharded(
-                    params, spec, x, size, mesh, with_bn_stats,
-                    chunk=chunk)
-            elif chunk and 0 < chunk < size:
-                group_sum, stacked_stats = _chunked_stack_sum(
-                    params, spec, x, size, chunk, with_bn_stats)
+        # one scope per group: a trace splits the ensemble's device time
+        # by architecture
+        with jax.named_scope(spec.kind):
+            if size == 1:
+                lg, _, stats = cnn_apply(params, spec, x, train=False)
+                group_sum = lg.astype(jnp.float32)
+                if with_bn_stats:
+                    all_stats.append(stats)
             else:
-                lgs, stacked_stats = _group_stack_forward(
-                    params, spec, x, size, with_bn_stats)
-                group_sum = jnp.sum(lgs, axis=0)
-            if with_bn_stats:
-                for k in range(size):
-                    all_stats.append(jax.tree.map(lambda a, _k=k: a[_k],
-                                                  stacked_stats))
+                if mesh is not None and group_shardable(mesh, size):
+                    group_sum, stacked_stats = _group_sum_sharded(
+                        params, spec, x, size, mesh, with_bn_stats,
+                        chunk=chunk)
+                elif chunk and 0 < chunk < size:
+                    group_sum, stacked_stats = _chunked_stack_sum(
+                        params, spec, x, size, chunk, with_bn_stats)
+                else:
+                    lgs, stacked_stats = _group_stack_forward(
+                        params, spec, x, size, with_bn_stats)
+                    group_sum = jnp.sum(lgs, axis=0)
+                if with_bn_stats:
+                    for k in range(size):
+                        all_stats.append(jax.tree.map(lambda a, _k=k: a[_k],
+                                                      stacked_stats))
         logits_sum = group_sum if logits_sum is None \
             else logits_sum + group_sum
     avg = logits_sum / m

@@ -138,6 +138,7 @@ def distill_kl(teacher_logits, student_logits, *, block_rows: int,
         out_specs=[pl.BlockSpec((br, 1), row_map)] * 6,
         out_shape=[jax.ShapeDtypeStruct((R, 1), jnp.float32)] * 6,
         interpret=interpret,
+        name="distill_kl_fwd",
     )(teacher_logits, student_logits)
     kl, mt, zt, st, ms, zs = (a[:, 0] for a in stats)
     if return_stats:
@@ -199,6 +200,7 @@ def distill_kl_bwd(teacher_logits, student_logits, lse_t, lse_s, kl, g, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="distill_kl_bwd",
     )(teacher_logits, student_logits,
       *(a.reshape(R, 1) for a in (lse_t, lse_s, kl, g)))
     if with_teacher_grad:
