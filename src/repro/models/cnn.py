@@ -19,6 +19,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import layers as L
 
 KINDS = ("cnn1", "cnn2", "resnet18", "wrn16_1", "wrn40_1", "lenet")
@@ -89,8 +90,10 @@ def _cnn_stack_apply(p, spec, x, train, sample_mask=None):
 
 # ------------------------------------------- grouped (m-client) fast path --
 #
-# Eval-mode forward of m same-spec conv-stack clients as ONE fused network.
-# Two static regimes, picked from the (trace-time) batch size:
+# Eval-mode forward of m same-spec clients as ONE fused network.
+#
+# Conv-stack kinds: two static regimes, picked from the (trace-time)
+# batch size:
 #
 #   * small batch (B < _GROUPED_IM2COL_MAX_B): im2col — every conv becomes
 #     patch extraction (9 shifted slices) + one client-batched einsum, so
@@ -102,9 +105,23 @@ def _cnn_stack_apply(p, spec, x, train, sample_mask=None):
 #     lax.map over the client axis runs the remaining layers as one
 #     compiled body executed m times. At large B all formulations are
 #     conv-FLOP-bound; this one never hits XLA-CPU's slow
-#     feature_group_count path and keeps memory O(1) in m.
+#     feature_group_count path.
 #
-# Both match the unrolled per-client forward to float tolerance.
+# Residual kinds (resnet18, wrn16_1, wrn40_1): lax.map of cnn_apply's own
+# eval forward over the client axis at every batch size — native
+# convolutions, one client at a time. On a TPU v5e this beat stacked
+# im2col GEMMs at every batch measured: the stage-2 generator step's
+# teacher pass (forward with BN stats and input gradient) over five
+# ResNet-18s took 2.9 / 4.5 / 21 ms this way against 4.3 / 11 / 83 ms
+# as im2col GEMMs at B = 8 / 16 / 128.
+#
+# lax.map keeps compile size O(1) in m, and memory O(1) in m for the
+# forward alone: under jax.grad the scan stacks every client's residuals,
+# unless the chunked teacher's jax.checkpoint re-runs them.
+#
+# All match the unrolled per-client forward to float tolerance.
+# ``ensemble.grouped_im2col`` / ``ensemble.grouped_native`` (repro.obs)
+# count, at trace time, the stacked groups each formulation took.
 
 _GROUPED_IM2COL_MAX_B = 32
 
@@ -173,37 +190,6 @@ def _conv3_im2col(h: jnp.ndarray, w: jnp.ndarray, m: int) -> jnp.ndarray:
         part = jnp.einsum(eq, rows[..., dy:dy + hh, :, :], wf)
         out = part if out is None else out + part
     return out
-
-
-def _conv_im2col(h: jnp.ndarray, w: jnp.ndarray, m: int,
-                 stride: int = 1) -> jnp.ndarray:
-    """k x k SAME conv (k in {1, 3}), any stride, as one im2col batched
-    GEMM — the strided generalization of ``_conv3_im2col`` the grouped
-    ResNet/WRN path needs (downsampling 3x3 blocks and 1x1 projections).
-
-    XLA's SAME convention is reproduced exactly: out = ceil(in/stride),
-    pad_total = max((out-1)*stride + k - in, 0), pad_lo = pad_total // 2
-    — NOT a stride-1 SAME conv subsampled afterwards, whose window
-    offsets differ for even inputs. Each kernel offset (dy, dx) then
-    contributes the strided slice hp[dy : dy+(out-1)*stride+1 : stride].
-    """
-    if w.shape[1] == 3 and stride == 1:
-        return _conv3_im2col(h, w, m)
-    k = w.shape[1]
-    hh, ww = h.shape[-3], h.shape[-2]
-    oh, ow = -(-hh // stride), -(-ww // stride)
-    pt_h = max((oh - 1) * stride + k - hh, 0)
-    pt_w = max((ow - 1) * stride + k - ww, 0)
-    pad = [(0, 0)] * (h.ndim - 3) + [(pt_h // 2, pt_h - pt_h // 2),
-                                     (pt_w // 2, pt_w - pt_w // 2), (0, 0)]
-    hp = jnp.pad(h, pad)
-    patches = jnp.concatenate(
-        [hp[..., dy:dy + (oh - 1) * stride + 1:stride,
-            dx:dx + (ow - 1) * stride + 1:stride, :]
-         for dy in range(k) for dx in range(k)], axis=-1)
-    eq = "bhwf,mfo->mbhwo" if h.ndim == 4 else "mbhwf,mfo->mbhwo"
-    return jnp.einsum(eq, patches,
-                      w.reshape(m, -1, w.shape[-1]).astype(h.dtype))
 
 
 def _grouped_im2col(stacked, x, m, with_stats):
@@ -292,56 +278,18 @@ def _grouped_conv_scan(stacked, x, m, with_stats):
     return logits, [l1_stats] + rest_stats
 
 
-def _grouped_cbr(lp, h, m, stats, with_stats, compute_dtype, *,
-                 stride=1, relu=True):
-    """conv+BN(+relu) of m stacked clients, eval mode — im2col GEMM with
-    either recorded batch stats (L_BN path) or BN folded into the kernel
-    (``_fold_bn``, stats-free path). h: shared (B,...) or per-client
-    (m, B, ...)."""
-    if with_stats:
-        pre32 = _conv_im2col(h, lp["conv"]["w"], m,
-                             stride).astype(jnp.float32)
-        stats.append({"mean": jnp.mean(pre32, (1, 2, 3)),
-                      "var": jnp.var(pre32, (1, 2, 3)),
-                      "running_mean": lp["bn"]["mean"],
-                      "running_var": lp["bn"]["var"]})
-        bn_b = jax.tree.map(lambda a: a[:, None, None, None, :], lp["bn"])
-        y = _bn_eval(bn_b, pre32, compute_dtype)
-    else:
-        wf, t = _fold_bn(lp["conv"]["w"], lp["bn"])
-        pre = _conv_im2col(h, wf, m, stride)
-        y = pre + t[:, None, None, None, :].astype(pre.dtype)
-    return jax.nn.relu(y) if relu else y
+def _grouped_resnet_map(stacked, spec, x, with_stats):
+    """Eval-mode forward of stacked same-spec ResNet/WRN clients:
+    ``lax.map`` of ``cnn_apply(train=False)`` over the client axis, so
+    every conv is a native convolution in x's dtype — the program a
+    singleton group runs, executed once per client. Stats come out in
+    ``_basic_apply``'s order (c1, c2, proj) with a leading client dim,
+    as vmapping cnn_apply gives them."""
+    def one(p):
+        logits, _, stats = cnn_apply(p, spec, x, train=False)
+        return logits, (stats if with_stats else [])
 
-
-def _grouped_resnet(stacked, spec, x, m, with_stats):
-    """Fused eval-mode forward of m same-spec ResNet/WRN clients.
-
-    Same contract as ``_grouped_im2col``; the residual topology
-    (stem -> stages of basic blocks -> global mean pool -> fc) mirrors
-    ``_resnet_apply`` with each conv an ``_conv_im2col`` batched GEMM,
-    and the stats list keeps ``_basic_apply``'s append order
-    (c1, c2, proj) so per-client slices line up with the vmapped
-    reference."""
-    stats = []
-    h = _grouped_cbr(stacked["stem"], x, m, stats, with_stats, x.dtype)
-    for s, blocks in enumerate(stacked["stages"]):
-        for b, bp in enumerate(blocks):
-            stride = 2 if (b == 0 and s > 0) else 1
-            y = _grouped_cbr(bp["c1"], h, m, stats, with_stats, x.dtype,
-                             stride=stride)
-            y = _grouped_cbr(bp["c2"], y, m, stats, with_stats, x.dtype,
-                             relu=False)
-            if "proj" in bp:
-                sc = _grouped_cbr(bp["proj"], h, m, stats, with_stats,
-                                  x.dtype, stride=stride, relu=False)
-            else:
-                sc = h
-            h = jax.nn.relu(y + sc)
-    feat = jnp.mean(h, axis=(2, 3))
-    logits = jnp.einsum("mbf,mfk->mbk", feat,
-                        stacked["fc"]["w"].astype(feat.dtype))
-    return logits + stacked["fc"]["b"][:, None, :].astype(logits.dtype), stats
+    return jax.lax.map(one, stacked)
 
 
 def cnn_stack_apply_grouped(stacked: dict, spec: CNNSpec, x: jnp.ndarray,
@@ -352,16 +300,22 @@ def cnn_stack_apply_grouped(stacked: dict, spec: CNNSpec, x: jnp.ndarray,
     (ensemble.stack_grouped). Returns (logits (m, B, K), bn_stats) with
     stats leaves carrying the leading client dim — the same contract as
     vmapping cnn_apply; stats is [] when with_stats=False, which also
-    lets the forward fold eval-mode BN into the conv kernels (_fold_bn).
-    Valid for every kind in _CNN_LAYOUT (conv-stack regimes picked by
-    batch size) and _RESNET_LAYOUT (``_grouped_resnet``) —
-    ``is_groupable``.
+    lets the conv-stack forwards fold eval-mode BN into the conv kernels
+    (_fold_bn).
+    Valid for every kind in _CNN_LAYOUT and _RESNET_LAYOUT
+    (``is_groupable``). Conv stacks run im2col GEMMs below
+    _GROUPED_IM2COL_MAX_B (``_grouped_im2col``) and native convolutions
+    from it on (``_grouped_conv_scan``); residual kinds run native
+    convolutions per client at every batch (``_grouped_resnet_map``).
     """
     if spec.kind in _RESNET_LAYOUT:
-        return _grouped_resnet(stacked, spec, x, m, with_stats)
+        obs.count("ensemble.grouped_native")
+        return _grouped_resnet_map(stacked, spec, x, with_stats)
     assert spec.kind in _CNN_LAYOUT, spec.kind
     if x.shape[0] < _GROUPED_IM2COL_MAX_B:
+        obs.count("ensemble.grouped_im2col")
         return _grouped_im2col(stacked, x, m, with_stats)
+    obs.count("ensemble.grouped_native")
     return _grouped_conv_scan(stacked, x, m, with_stats)
 
 

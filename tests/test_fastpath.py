@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs.paper_cifar import DenseExperimentConfig
 from repro.core import losses as LS
 from repro.core import train_dense_server
@@ -16,7 +17,8 @@ from repro.core.ensemble import (Client, ensemble_logits,
                                  grouped_ensemble_logits, group_clients,
                                  split_clients, stack_grouped,
                                  stack_homogeneous)
-from repro.models.cnn import CNNSpec, cnn_init, cnn_logits
+from repro.models.cnn import (_GROUPED_IM2COL_MAX_B, CNNSpec, cnn_init,
+                              cnn_logits)
 
 
 def _mk_clients(kinds, seed0=0, **spec_kw):
@@ -71,14 +73,16 @@ def test_grouped_matches_unrolled_mixed_architectures(batch):
                                float(LS.bn_loss(ref_stats)), rtol=1e-4)
 
 
+@pytest.mark.parametrize("batch", [4, 32])  # both sides of the B rule
 @pytest.mark.parametrize("kind", ["wrn16_1", "resnet18"])
-def test_grouped_residual_stack_matches_unrolled(kind):
-    """Size->=2 residual groups run the fused stacked forward
-    (models.cnn._grouped_resnet) instead of vmapped cnn_apply: logits
-    and L_BN inputs must match the unrolled reference — including the
-    projection-shortcut stats slots and strided SAME conv geometry."""
+def test_grouped_residual_stack_matches_unrolled(kind, batch):
+    """Size->=2 residual groups run the stacked forward
+    (models.cnn._grouped_resnet_map) at every batch size: logits and
+    L_BN inputs must match the unrolled reference — including the
+    projection-shortcut stats slots — and so must the stats-free
+    eval-only branch."""
     clients = _mk_clients((kind,) * 3)
-    x = jax.random.normal(jax.random.PRNGKey(3), (4, 16, 16, 3))
+    x = jax.random.normal(jax.random.PRNGKey(3), (batch, 16, 16, 3))
     specs, cparams = split_clients(clients)
     gspecs, gparams = stack_grouped(clients)
     ref, ref_stats = ensemble_logits(specs, cparams, x, with_bn_stats=True)
@@ -88,11 +92,54 @@ def test_grouped_residual_stack_matches_unrolled(kind):
                                atol=5e-4)
     np.testing.assert_allclose(float(LS.bn_loss(got_stats)),
                                float(LS.bn_loss(ref_stats)), rtol=1e-3)
-    # eval-only path (folded-BN branch) agrees too
+    # eval-only path (no stats) agrees too
     got_e = grouped_ensemble_logits(gspecs, gparams, x)
     ref_e = ensemble_logits(specs, cparams, x)
     np.testing.assert_allclose(np.asarray(got_e), np.asarray(ref_e),
                                atol=5e-4)
+
+
+@pytest.mark.parametrize("chunk", [None, 2])
+def test_grouped_residual_input_grad_matches_unrolled(chunk):
+    """The generator step's path through the teacher: d(L_CE + L_BN)/dx
+    of a stacked ResNet group at B = 32 matches the unrolled reference
+    — unchunked, and chunked with a remainder (3 clients in chunks of
+    2)."""
+    clients = _mk_clients(("resnet18",) * 3)
+    x = jax.random.normal(jax.random.PRNGKey(5), (32, 16, 16, 3))
+    y = jnp.arange(x.shape[0]) % 6
+    specs, cparams = split_clients(clients)
+    gspecs, gparams = stack_grouped(clients)
+
+    def loss(avg, stats):
+        return LS.ce_loss(avg, y) + LS.bn_loss(stats)
+
+    g_ref = jax.grad(lambda xx: loss(*ensemble_logits(
+        specs, cparams, xx, with_bn_stats=True)))(x)
+    g_got = jax.grad(lambda xx: loss(*grouped_ensemble_logits(
+        gspecs, gparams, xx, with_bn_stats=True, chunk=chunk)))(x)
+    scale = float(jnp.max(jnp.abs(g_ref)))
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
+                               atol=1e-3 * scale)
+
+
+@pytest.mark.parametrize("kind,batch,name", [
+    ("cnn1", 4, "ensemble.grouped_im2col"),
+    ("cnn1", _GROUPED_IM2COL_MAX_B, "ensemble.grouped_native"),
+    ("wrn16_1", 4, "ensemble.grouped_native")])
+def test_grouped_regime_counters(kind, batch, name):
+    """repro.obs counts, per stacked group traced, which formulation the
+    grouped forward took: conv stacks im2col below
+    _GROUPED_IM2COL_MAX_B and native convolutions from it on, residual
+    kinds native convolutions at every batch."""
+    clients = _mk_clients((kind,) * 2)
+    gspecs, gparams = stack_grouped(clients)
+    obs.reset()
+    x = jnp.zeros((batch, 16, 16, 3))
+    jax.jit(lambda gp, xx: grouped_ensemble_logits(gspecs, gp, xx))(
+        gparams, x)
+    assert obs.snapshot()["counters"] == {name: 1}
+    obs.reset()
 
 
 def test_grouped_matches_under_jit_homogeneous():

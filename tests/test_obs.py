@@ -171,8 +171,11 @@ def test_fused_chunk_spans_show_in_a_profile(tmp_path):
     finally:
         jax.profiler.stop_trace()
     snap = obs.snapshot()
+    # the stacked cnn1 pair at synth batch 8 is traced once in each of
+    # the two steps, as im2col GEMMs
     assert snap["counters"] == {"dense.chunks": 2, "dense.epochs": 4,
-                                "dense.host_syncs": 2}
+                                "dense.host_syncs": 2,
+                                "ensemble.grouped_im2col": 2}
     chunks = _spans("dense.chunk")
     assert [c["attrs"] for c in chunks] == [{"lo": 0, "hi": 2},
                                             {"lo": 2, "hi": 4}]
