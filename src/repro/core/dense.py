@@ -106,6 +106,24 @@ def make_dense_steps(clients: Sequence[Client], student_spec: CNNSpec,
         from repro.fl.sharding import resolve_mesh
         mesh = resolve_mesh(pol)
     kl_mode = pol.distill_kl
+    if mesh is not None and kl_mode == "fused":
+        # the compiler cannot partition a Pallas kernel: on a mesh each
+        # device runs the fused KL pair whole on its replicated logits
+        # (shard_map's transpose psums the cotangents of replicated
+        # operands after dividing the output's by the device count, so
+        # the gradients are those of one device)
+        from jax.sharding import PartitionSpec as P
+
+        def _kl(fn):
+            return jax.shard_map(fn, mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False)
+    else:
+        def _kl(fn):
+            return fn
+    div_loss = _kl(functools.partial(LS.div_loss, mode=kl_mode,
+                                     policy=pol))
+    distill_loss = _kl(functools.partial(
+        LS.distill_loss, mode=kl_mode, with_teacher_grad=False, policy=pol))
     # nan_policy="skip" compiles an isfinite guard into BOTH steps: a
     # non-finite loss (or grad) step becomes a no-op update via
     # jnp.where over the param/opt-state trees. Any other policy
@@ -144,8 +162,7 @@ def make_dense_steps(clients: Sequence[Client], student_spec: CNNSpec,
             with jax.named_scope(obs.LOSS):
                 l_ce = LS.ce_loss(avg, y)
                 l_bn = LS.bn_loss(stats) if use_bn else jnp.zeros(())
-                l_div = LS.div_loss(avg, stu, mode=kl_mode, policy=pol) \
-                    if use_div else jnp.zeros(())
+                l_div = div_loss(avg, stu) if use_div else jnp.zeros(())
                 total = l_ce + scfg.lambda_bn * l_bn \
                     + scfg.lambda_div * l_div
             return total, {"ce": l_ce, "bn": l_bn, "div": l_div}
@@ -177,8 +194,7 @@ def make_dense_steps(clients: Sequence[Client], student_spec: CNNSpec,
             with jax.named_scope(obs.LOSS):
                 # avg is stop-gradient'd upstream: skip the fused dL/dt
                 # stream
-                loss = LS.distill_loss(avg, logits, mode=kl_mode,
-                                       with_teacher_grad=False, policy=pol)
+                loss = distill_loss(avg, logits)
             return loss, new_sp
 
         (loss, stats_p), grads = jax.value_and_grad(loss_fn, has_aux=True)(stu_p)
@@ -291,7 +307,10 @@ def train_dense_server(key, clients: Sequence[Client], scfg,
     module docstring).
     scfg.ensemble_shard_mode="clients" additionally shards the frozen
     client stack over a ("clients", "data") mesh (fl/sharding.py) — a
-    pure placement/lowering choice, same math (DESIGN.md §8).
+    pure placement/lowering choice, same math (DESIGN.md §8); the
+    generator, the student, both optimizer states and the epoch keys
+    are then placed replicated on the mesh. The ``dense.setup`` span
+    carries the mesh's shape (``mesh="clients=4,data=1"``, or "none").
     scfg.distill_kl_mode selects the stage-2 KL implementation ("ref"
     jnp autodiff or "fused" Pallas custom-VJP pair, DESIGN.md §9) —
     also a pure implementation choice, same math.
@@ -335,11 +354,16 @@ def train_dense_server(key, clients: Sequence[Client], scfg,
     ck_path = getattr(scfg, "checkpoint_path", "") or ""
     ckpt_on = bool(ck_every and ck_path)
     start_epoch = 0
-    with obs.span("dense.setup"):
+    from repro.fl.sharding import put_replicated, resolve_mesh
+    mesh = resolve_mesh(resolve_exec_policy(scfg))
+    mesh_shape = "none" if mesh is None else ",".join(
+        f"{a}={n}" for a, n in mesh.shape.items())
+    with obs.span("dense.setup", mesh=mesh_shape):
         with obs.span("dense.make_steps"):
             (gen_step, student_step, g_opt, s_opt, gparams, epoch_step,
              epochs_step) = make_dense_steps(clients, student_spec, scfg,
-                                             use_bn=use_bn, use_div=use_div)
+                                             use_bn=use_bn, use_div=use_div,
+                                             mesh=mesh)
         with obs.span("dense.init"):
             k_gen, k_stu, key = jax.random.split(key, 3)
             gen_p = G.img_generator_init(k_gen, nz=scfg.nz,
@@ -358,6 +382,11 @@ def train_dense_server(key, clients: Sequence[Client], scfg,
                 gen_p, g_state = st["gen_p"], st["g_state"]
                 stu_p, s_state = st["stu_p"], st["s_state"]
                 key, start_epoch = st["key"], int(st["epoch"])
+        # on a client mesh every carry is placed replicated beside the
+        # client-sharded stack, so the first chunk compiles for the
+        # placement its outputs keep and later chunks reuse that program
+        gen_p, g_state, stu_p, s_state, key = put_replicated(
+            (gen_p, g_state, stu_p, s_state, key), mesh)
 
     def save_ckpt(gp, gs, sp, ss, epoch_done):
         with obs.span("dense.checkpoint"):

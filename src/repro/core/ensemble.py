@@ -277,27 +277,55 @@ def _group_sum_sharded(params, spec, x, size, mesh, with_stats,
     with ``chunk`` set, to one psum per scanned sub-chunk
     (``_chunked_stack_sum``), keeping the replicated fp32 accumulator
     exact while no shard ever materializes its full local logit block.
+    The psum runs in named scope ``psum`` under the group's, so a trace
+    charges the collective to the teacher. With stats, each shard's
+    per-client BN statistics are gathered inside the shard_map by one
+    tiled all-gather of the leaves packed side by side (scope
+    ``all_gather``): slicing one client out of a client-sharded stats
+    stack would cost a collective-permute per client and leaf (1,200 a
+    generator step for 20 ResNet-18s on four devices), and gathering
+    leaf by leaf one all-gather per leaf (102).
 
     Returns (group_sum (B, K) f32 replicated, stacked stats with the full
-    (size, ...) leading dim sharded over ``clients``). Callers guarantee
-    divisibility (fl.sharding.group_shardable).
+    (size, ...) leading dim, replicated). Callers guarantee divisibility
+    (fl.sharding.group_shardable).
     """
     from repro.fl.sharding import CLIENT_AXIS, client_axis_size
 
     loc = size // client_axis_size(mesh)
 
+    def psum(v):
+        with jax.named_scope("psum"):
+            return jax.lax.psum(v, CLIENT_AXIS)
+
+    def gather(tree):
+        leaves, treedef = jax.tree.flatten(tree)
+        out = [None] * len(leaves)
+        for dt in dict.fromkeys(a.dtype for a in leaves):
+            idx = [i for i, a in enumerate(leaves) if a.dtype == dt]
+            packed = jnp.concatenate(
+                [leaves[i].reshape(loc, -1) for i in idx], axis=1)
+            with jax.named_scope("all_gather"):
+                full = jax.lax.all_gather(packed, CLIENT_AXIS, tiled=True)
+            off = 0
+            for i in idx:
+                n = leaves[i].size // loc
+                out[i] = full[:, off:off + n].reshape(
+                    (size,) + leaves[i].shape[1:])
+                off += n
+        return jax.tree.unflatten(treedef, out)
+
     def local(p_shard, xb):
         if chunk and 0 < chunk < loc:
             s, st = _chunked_stack_sum(
-                p_shard, spec, xb, loc, chunk, with_stats,
-                reduce=lambda v: jax.lax.psum(v, CLIENT_AXIS))
+                p_shard, spec, xb, loc, chunk, with_stats, reduce=psum)
         else:
             lgs, st = _group_stack_forward(p_shard, spec, xb, loc,
                                            with_stats)
-            s = jax.lax.psum(jnp.sum(lgs, axis=0), CLIENT_AXIS)
-        return (s, st) if with_stats else s
+            s = psum(jnp.sum(lgs, axis=0))
+        return (s, gather(st)) if with_stats else s
 
-    out_specs = (P(), P(CLIENT_AXIS)) if with_stats else P()
+    out_specs = (P(), P()) if with_stats else P()
     out = jax.shard_map(local, mesh=mesh, in_specs=(P(CLIENT_AXIS), P()),
                         out_specs=out_specs, check_vma=False)(params, x)
     return out if with_stats else (out, [])
@@ -316,7 +344,9 @@ def grouped_ensemble_logits(gspecs, gparams, x: jnp.ndarray, *,
     mesh: optional ("clients", "data") mesh (fl/sharding.py). Stacked
     groups whose size the ``clients`` axis divides evaluate as one
     shard_map whose group sum is a single psum over that axis; other
-    groups (and singletons) keep the single-device path.
+    groups (and singletons) keep the single-device path. Each stacked
+    group's clients are counted, at trace time, as sharded or
+    replicated (``fl.sharding.shards_group``).
 
     group_masks: optional per-group survivor masks (fl.protocol
     admission). Statically sliced out up front (``apply_group_masks``),
@@ -337,7 +367,7 @@ def grouped_ensemble_logits(gspecs, gparams, x: jnp.ndarray, *,
     if group_masks is not None:
         gspecs, gparams = apply_group_masks(gspecs, gparams, group_masks)
     if mesh is not None:
-        from repro.fl.sharding import group_shardable
+        from repro.fl.sharding import shards_group
     m = sum(size for _, size in gspecs)
     logits_sum = None
     all_stats = []
@@ -351,7 +381,8 @@ def grouped_ensemble_logits(gspecs, gparams, x: jnp.ndarray, *,
                 if with_bn_stats:
                     all_stats.append(stats)
             else:
-                if mesh is not None and group_shardable(mesh, size):
+                if mesh is not None and shards_group(mesh, size,
+                                                     spec.kind):
                     group_sum, stacked_stats = _group_sum_sharded(
                         params, spec, x, size, mesh, with_bn_stats,
                         chunk=chunk)

@@ -26,19 +26,27 @@ single place that maps that dim onto a mesh:
 A group only shards when its size is divisible by the ``clients`` axis
 (``group_shardable``); otherwise it is placed replicated and the existing
 single-device vmap path runs unchanged — ``ensemble_shard_mode="clients"``
-is therefore always correctness-safe, on any device count. Equivalence is
+is therefore always correctness-safe, on any device count. The teacher
+records which way each stacked group went (``shards_group``): counters
+``ensemble.sharded_clients`` / ``ensemble.replicated_clients`` and, for a
+group that falls back, one warning per group shape. Equivalence is
 exercised on CPU via ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
 (tests/test_client_sharding.py, CI job ``sharding-equivalence``).
 """
 from __future__ import annotations
 
+import warnings
+
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.backend import SHARD_MODES, resolve_exec_policy
 from repro.launch.mesh import make_client_mesh
 
 CLIENT_AXIS = "clients"
+# (kind, group size, clients axis) of the fallbacks already warned about
+_WARNED: set = set()
 
 
 def resolve_mesh(scfg):
@@ -65,6 +73,28 @@ def group_shardable(mesh, size: int) -> bool:
     shard then carries size // axis whole clients)."""
     return mesh is not None and size > 1 \
         and size % client_axis_size(mesh) == 0
+
+
+def shards_group(mesh, size: int, kind: str) -> bool:
+    """``group_shardable``, recorded for a stacked group of the teacher
+    (trace time): its clients are added to ``ensemble.sharded_clients``
+    or, where the mesh leaves the group replicated, to
+    ``ensemble.replicated_clients``, and the first fallback of each
+    group shape warns, since every device then runs the whole group."""
+    ok = group_shardable(mesh, size)
+    if mesh is None or size < 2:
+        return ok
+    obs.count("ensemble.sharded_clients" if ok
+              else "ensemble.replicated_clients", size)
+    shape = (kind, size, client_axis_size(mesh))
+    if not ok and shape not in _WARNED:
+        _WARNED.add(shape)
+        warnings.warn(
+            f"ensemble_shard_mode='clients': the {shape[2]}-way "
+            f"{CLIENT_AXIS!r} axis does not divide the {size}-client {kind} "
+            "group, which runs replicated on every device", RuntimeWarning,
+            stacklevel=2)
+    return ok
 
 
 def stack_specs(inner_specs, axis):
@@ -116,6 +146,7 @@ def put_grouped(gspecs, gparams, mesh):
 
 
 __all__ = ["CLIENT_AXIS", "SHARD_MODES", "resolve_mesh", "client_axis_size",
-           "group_shardable", "stack_specs", "client_stack_sharding",
+           "group_shardable", "shards_group", "stack_specs",
+           "client_stack_sharding",
            "replicated_sharding", "put_stacked", "put_replicated",
            "put_grouped"]

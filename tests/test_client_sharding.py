@@ -286,3 +286,43 @@ def test_dense_server_shard_mode_equivalence():
     np.testing.assert_allclose(outs["none"][1].gen_loss,
                                outs["clients"][1].gen_loss,
                                rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("kl", ["ref", "fused"])
+def test_dense_fused_chunk_on_client_mesh_matches_unsharded(kl):
+    """train_dense_server's fused chunk driver (one scanned program of
+    loop_chunk epochs, donated carries) on the ("clients", "data") mesh
+    against the same call without a mesh: eight clients of one residual
+    kind, so the group shards on 1, 2, 4 or 8 devices. Same math, not
+    the same bits (DESIGN.md §8): the first chunk's student within 5e-5
+    and its losses within 1e-3. With the Pallas distill_kl pair (here in
+    interpret mode) each device runs it whole on replicated logits. Every
+    carry comes back replicated on the mesh, and the teacher counts all
+    eight clients as sharded."""
+    from repro import obs
+    from repro.core import train_dense_server
+    clients = _mk_clients(("wrn16_1",) * 8, num_classes=SCFG.num_classes)
+    outs = {}
+    for mode in ("none", "clients"):
+        s = dataclasses.replace(SCFG, ensemble_shard_mode=mode,
+                                loop_mode="fused", loop_chunk=2, epochs=2,
+                                distill_kl_mode=kl)
+        obs.reset()
+        stu, gen, hist = train_dense_server(jax.random.PRNGKey(5), clients,
+                                            s)
+        outs[mode] = (stu, hist, obs.snapshot())
+    (stu0, h0, snap0), (stu1, h1, snap1) = outs["none"], outs["clients"]
+    assert _tree_max_diff(stu0, stu1) < 5e-5
+    np.testing.assert_allclose(h0.gen_loss, h1.gen_loss, rtol=1e-3,
+                               atol=1e-5)
+    np.testing.assert_allclose(h0.dis_loss, h1.dis_loss, rtol=1e-3,
+                               atol=1e-5)
+    n = len(jax.devices())
+    for leaf in jax.tree.leaves(stu1):
+        assert leaf.sharding.is_fully_replicated
+        assert len(leaf.sharding.device_set) == n
+    assert snap1["counters"]["ensemble.sharded_clients"] > 0
+    assert "ensemble.replicated_clients" not in snap1["counters"]
+    assert "ensemble.sharded_clients" not in snap0["counters"]
+    setup = {s["name"]: s["attrs"] for s in snap1["spans"]}["dense.setup"]
+    assert setup == {"mesh": f"clients={n},data=1"}

@@ -4,6 +4,7 @@ import dataclasses
 import glob
 import re
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -249,3 +250,46 @@ def test_every_conv_and_dot_of_the_chunk_carries_a_scope():
             groups.add(re.search(r"teacher\)*/(\w+)", name.group(1))[1])
     assert seen == {obs.TEACHER, obs.STUDENT, obs.GENERATOR}
     assert groups == {"cnn1", "resnet18"}
+
+
+def test_teacher_counts_the_clients_a_mesh_shards():
+    """Tracing the teacher on a client mesh that divides its stacked
+    group counts the group's clients as sharded; a singleton group is
+    neither, and without a mesh nothing is counted."""
+    from repro.core.ensemble import grouped_ensemble_logits, stack_grouped
+    from repro.fl import sharding as FS
+    from repro.launch.mesh import make_client_mesh
+    clients = _clients(("cnn1",) * 8 + ("resnet18",))
+    gspecs, gparams = stack_grouped(clients)
+    x = jnp.zeros((4, 8, 8, 1))
+    grouped_ensemble_logits(gspecs, gparams, x)
+    assert not any(k.endswith("_clients")
+                   for k in obs.snapshot()["counters"])
+    mesh = make_client_mesh()
+    placed = FS.put_grouped(gspecs, gparams, mesh)
+    jax.jit(lambda gp, xb: grouped_ensemble_logits(
+        gspecs, gp, xb, with_bn_stats=True, mesh=mesh)).lower(placed, x)
+    counters = obs.snapshot()["counters"]
+    assert counters["ensemble.sharded_clients"] == 8
+    assert "ensemble.replicated_clients" not in counters
+
+
+def test_a_group_the_mesh_cannot_shard_is_counted_and_warned_once():
+    """A stacked group that the clients axis does not divide runs
+    replicated: its clients are counted as such, and the first fallback
+    of each group shape warns."""
+    from types import SimpleNamespace
+
+    from repro.fl import sharding as FS
+    mesh = SimpleNamespace(shape={"clients": 7, "data": 1})
+    assert FS.shards_group(mesh, 14, "cnn1")
+    with pytest.warns(RuntimeWarning, match="3-client cnn1 group"):
+        assert not FS.shards_group(mesh, 3, "cnn1")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert not FS.shards_group(mesh, 3, "cnn1")
+        assert not FS.shards_group(mesh, 1, "cnn1")
+        assert not FS.shards_group(None, 3, "cnn1")
+    counters = obs.snapshot()["counters"]
+    assert counters["ensemble.sharded_clients"] == 14
+    assert counters["ensemble.replicated_clients"] == 6
