@@ -107,23 +107,43 @@ def _cnn_stack_apply(p, spec, x, train, sample_mask=None):
 #     conv-FLOP-bound; this one never hits XLA-CPU's slow
 #     feature_group_count path.
 #
-# Residual kinds (resnet18, wrn16_1, wrn40_1): lax.map of cnn_apply's own
-# eval forward over the client axis at every batch size — native
-# convolutions, one client at a time. On a TPU v5e this beat stacked
-# im2col GEMMs at every batch measured: the stage-2 generator step's
-# teacher pass (forward with BN stats and input gradient) over five
-# ResNet-18s took 2.9 / 4.5 / 21 ms this way against 4.3 / 11 / 83 ms
-# as im2col GEMMs at B = 8 / 16 / 128.
+# Residual kinds (resnet18, wrn16_1, wrn40_1): cnn_apply's own eval
+# forward once per client at every batch size — native convolutions, one
+# client at a time. On a TPU v5e this beat stacked im2col GEMMs at every
+# batch measured: the stage-2 generator step's teacher pass (forward with
+# BN stats and input gradient) over five ResNet-18s took 2.9 / 4.5 / 21 ms
+# this way against 4.3 / 11 / 83 ms as im2col GEMMs at B = 8 / 16 / 128.
 #
-# lax.map keeps compile size O(1) in m, and memory O(1) in m for the
-# forward alone: under jax.grad the scan stacks every client's residuals,
-# unless the chunked teacher's jax.checkpoint re-runs them.
+# Up to _GROUPED_UNROLL_MAX_M clients the per-client body runs as a fully
+# unrolled lax.scan. Under jax.grad a rolled loop (lax.map) writes every
+# client's residuals (BN inputs, ReLU masks) into (m, B, ...) stacks, one
+# dynamic-update-slice per residual per client, and the backward reads
+# them back: on a v5e that stacking took >= 96 ms of the 650 ms five
+# ResNet-18s spend an epoch in the teacher. Unrolled, XLA folds each
+# slice of a stack back into the client's own buffer, so no stack is
+# built, as in a singleton group. The body is still traced once.
+#
+# Above the bound, lax.map keeps compile size O(1) in m, and memory O(1)
+# in m for the forward alone: under jax.grad the scan stacks every
+# client's residuals, unless the chunked teacher's jax.checkpoint re-runs
+# them.
 #
 # All match the unrolled per-client forward to float tolerance.
-# ``ensemble.grouped_im2col`` / ``ensemble.grouped_native`` (repro.obs)
-# count, at trace time, the stacked groups each formulation took.
+# ``ensemble.grouped_im2col`` / ``ensemble.grouped_native`` /
+# ``ensemble.grouped_unrolled`` (repro.obs) count, at trace time, the
+# stacked groups each formulation took.
 
 _GROUPED_IM2COL_MAX_B = 32
+
+# Largest stacked residual group that runs unrolled. Compile time grows
+# linearly in the unrolled count: a cold compile of the generator step's
+# teacher pass over five ResNet-18s at B = 128, for a v5e, took 77-90 s
+# unrolled against 20 s as lax.map, so eight would take about 2 minutes.
+# 8 covers the paper's five clients and the five a chip of a 20-client
+# group sharded over four chips; larger groups keep lax.map's compile,
+# O(1) in m, and the chunked teacher's slices unroll only up to their
+# chunk size.
+_GROUPED_UNROLL_MAX_M = 8
 
 
 def _grouped_kernel(w: jnp.ndarray) -> jnp.ndarray:
@@ -280,15 +300,23 @@ def _grouped_conv_scan(stacked, x, m, with_stats):
 
 def _grouped_resnet_map(stacked, spec, x, with_stats):
     """Eval-mode forward of stacked same-spec ResNet/WRN clients:
-    ``lax.map`` of ``cnn_apply(train=False)`` over the client axis, so
-    every conv is a native convolution in x's dtype — the program a
-    singleton group runs, executed once per client. Stats come out in
+    ``cnn_apply(train=False)`` once per client, so every conv is a native
+    convolution in x's dtype — the program a singleton group runs. Up to
+    _GROUPED_UNROLL_MAX_M clients (read from the stack's leading dim) a
+    fully unrolled ``lax.scan``, above it ``lax.map``. Stats come out in
     ``_basic_apply``'s order (c1, c2, proj) with a leading client dim,
     as vmapping cnn_apply gives them."""
     def one(p):
         logits, _, stats = cnn_apply(p, spec, x, train=False)
         return logits, (stats if with_stats else [])
 
+    m = jax.tree.leaves(stacked)[0].shape[0]
+    if m <= _GROUPED_UNROLL_MAX_M:
+        obs.count("ensemble.grouped_unrolled")
+        _, out = jax.lax.scan(lambda c, p: (c, one(p)), None, stacked,
+                              unroll=m)
+        return out
+    obs.count("ensemble.grouped_native")
     return jax.lax.map(one, stacked)
 
 
@@ -306,10 +334,10 @@ def cnn_stack_apply_grouped(stacked: dict, spec: CNNSpec, x: jnp.ndarray,
     (``is_groupable``). Conv stacks run im2col GEMMs below
     _GROUPED_IM2COL_MAX_B (``_grouped_im2col``) and native convolutions
     from it on (``_grouped_conv_scan``); residual kinds run native
-    convolutions per client at every batch (``_grouped_resnet_map``).
+    convolutions per client at every batch, unrolled up to
+    _GROUPED_UNROLL_MAX_M clients (``_grouped_resnet_map``).
     """
     if spec.kind in _RESNET_LAYOUT:
-        obs.count("ensemble.grouped_native")
         return _grouped_resnet_map(stacked, spec, x, with_stats)
     assert spec.kind in _CNN_LAYOUT, spec.kind
     if x.shape[0] < _GROUPED_IM2COL_MAX_B:

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.configs.paper_cifar import DenseExperimentConfig
 from repro.core import losses as LS
 from repro.core.ensemble import (Client, ensemble_logits,
@@ -165,8 +166,9 @@ def test_sharded_ensemble_matches_unsharded(kinds):
 @pytest.mark.parametrize("chunk", [None, 2])
 def test_sharded_residual_group_matches_unrolled(chunk):
     """A stacked residual group under the client-sharded teacher: each
-    shard runs models.cnn._grouped_resnet_map (lax.map of cnn_apply)
-    with its BN stats sharded over ``clients``. At B=32, logits, L_BN
+    shard runs models.cnn._grouped_resnet_map (cnn_apply unrolled over
+    its clients, ``ensemble.grouped_unrolled``) with its BN stats
+    gathered over ``clients``. At B=32, logits, L_BN
     and the generator's input gradient match the unrolled ensemble.
     Eight clients, so the group splits on 1, 2, 4 or 8 devices; with
     chunk=2 every shard holding more than two clients streams them."""
@@ -190,7 +192,12 @@ def test_sharded_residual_group_matches_unrolled(chunk):
         avg, stats = out
         return LS.ce_loss(avg, y) + LS.bn_loss(stats)
 
+    obs.reset()
     got, got_stats = jax.jit(sharded)(x)
+    regimes = obs.snapshot()["counters"]
+    assert regimes.get("ensemble.grouped_unrolled", 0) >= 1
+    assert "ensemble.grouped_native" not in regimes
+    obs.reset()
     ref, ref_stats = unrolled(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
     assert len(got_stats) == len(clients)

@@ -17,14 +17,14 @@ from repro.core.ensemble import (Client, ensemble_logits,
                                  grouped_ensemble_logits, group_clients,
                                  split_clients, stack_grouped,
                                  stack_homogeneous)
-from repro.models.cnn import (_GROUPED_IM2COL_MAX_B, CNNSpec, cnn_init,
-                              cnn_logits)
+from repro.models.cnn import (_GROUPED_IM2COL_MAX_B, _GROUPED_UNROLL_MAX_M,
+                              CNNSpec, cnn_init, cnn_logits)
 
 
-def _mk_clients(kinds, seed0=0, **spec_kw):
+def _mk_clients(kinds, seed0=0, width=0.5, **spec_kw):
     clients = []
     for i, k in enumerate(kinds):
-        sp = CNNSpec(kind=k, num_classes=6, in_ch=3, width=0.5,
+        sp = CNNSpec(kind=k, num_classes=6, in_ch=3, width=width,
                      image_size=16, **spec_kw)
         clients.append(Client(spec=sp,
                               params=cnn_init(jax.random.PRNGKey(seed0 + i),
@@ -73,15 +73,24 @@ def test_grouped_matches_unrolled_mixed_architectures(batch):
                                float(LS.bn_loss(ref_stats)), rtol=1e-4)
 
 
+# group sizes on both sides of the unroll bound: an unrolled scan, and
+# lax.map (at smaller widths, to keep the larger group cheap)
+RESIDUAL_GROUPS = pytest.mark.parametrize(
+    "m,width", [(3, 0.5), (_GROUPED_UNROLL_MAX_M + 1, 0.25)],
+    ids=["unrolled", "map"])
+
+
+@RESIDUAL_GROUPS
 @pytest.mark.parametrize("batch", [4, 32])  # both sides of the B rule
 @pytest.mark.parametrize("kind", ["wrn16_1", "resnet18"])
-def test_grouped_residual_stack_matches_unrolled(kind, batch):
+def test_grouped_residual_stack_matches_unrolled(kind, batch, m, width):
     """Size->=2 residual groups run the stacked forward
-    (models.cnn._grouped_resnet_map) at every batch size: logits and
-    L_BN inputs must match the unrolled reference — including the
+    (models.cnn._grouped_resnet_map) at every batch size, unrolled up to
+    _GROUPED_UNROLL_MAX_M clients and as lax.map above: logits and L_BN
+    inputs must match the unrolled reference — including the
     projection-shortcut stats slots — and so must the stats-free
     eval-only branch."""
-    clients = _mk_clients((kind,) * 3)
+    clients = _mk_clients((kind,) * m, width=width)
     x = jax.random.normal(jax.random.PRNGKey(3), (batch, 16, 16, 3))
     specs, cparams = split_clients(clients)
     gspecs, gparams = stack_grouped(clients)
@@ -99,13 +108,14 @@ def test_grouped_residual_stack_matches_unrolled(kind, batch):
                                atol=5e-4)
 
 
+@RESIDUAL_GROUPS
 @pytest.mark.parametrize("chunk", [None, 2])
-def test_grouped_residual_input_grad_matches_unrolled(chunk):
+def test_grouped_residual_input_grad_matches_unrolled(chunk, m, width):
     """The generator step's path through the teacher: d(L_CE + L_BN)/dx
     of a stacked ResNet group at B = 32 matches the unrolled reference
-    — unchunked, and chunked with a remainder (3 clients in chunks of
+    — unchunked, and chunked with a remainder (an odd group in chunks of
     2)."""
-    clients = _mk_clients(("resnet18",) * 3)
+    clients = _mk_clients(("resnet18",) * m, width=width)
     x = jax.random.normal(jax.random.PRNGKey(5), (32, 16, 16, 3))
     y = jnp.arange(x.shape[0]) % 6
     specs, cparams = split_clients(clients)
@@ -123,16 +133,18 @@ def test_grouped_residual_input_grad_matches_unrolled(chunk):
                                atol=1e-3 * scale)
 
 
-@pytest.mark.parametrize("kind,batch,name", [
-    ("cnn1", 4, "ensemble.grouped_im2col"),
-    ("cnn1", _GROUPED_IM2COL_MAX_B, "ensemble.grouped_native"),
-    ("wrn16_1", 4, "ensemble.grouped_native")])
-def test_grouped_regime_counters(kind, batch, name):
+@pytest.mark.parametrize("kind,batch,m,name", [
+    ("cnn1", 4, 2, "ensemble.grouped_im2col"),
+    ("cnn1", _GROUPED_IM2COL_MAX_B, 2, "ensemble.grouped_native"),
+    ("wrn16_1", 4, 2, "ensemble.grouped_unrolled"),
+    ("wrn16_1", 4, _GROUPED_UNROLL_MAX_M + 1, "ensemble.grouped_native")])
+def test_grouped_regime_counters(kind, batch, m, name):
     """repro.obs counts, per stacked group traced, which formulation the
     grouped forward took: conv stacks im2col below
     _GROUPED_IM2COL_MAX_B and native convolutions from it on, residual
-    kinds native convolutions at every batch."""
-    clients = _mk_clients((kind,) * 2)
+    kinds native convolutions at every batch, unrolled up to
+    _GROUPED_UNROLL_MAX_M clients and under lax.map above."""
+    clients = _mk_clients((kind,) * m)
     gspecs, gparams = stack_grouped(clients)
     obs.reset()
     x = jnp.zeros((batch, 16, 16, 3))
@@ -140,6 +152,26 @@ def test_grouped_regime_counters(kind, batch, name):
         gparams, x)
     assert obs.snapshot()["counters"] == {name: 1}
     obs.reset()
+
+
+@pytest.mark.parametrize("m,loops", [(3, False),
+                                     (_GROUPED_UNROLL_MAX_M + 1, True)])
+def test_grouped_residual_unroll_structure(m, loops):
+    """The generator step's input gradient through a stacked residual
+    group lowers with no loop up to _GROUPED_UNROLL_MAX_M clients — a
+    loop under jax.grad stacks every client's residuals — and with one
+    above the bound."""
+    clients = _mk_clients(("wrn16_1",) * m, width=0.25)
+    gspecs, gparams = stack_grouped(clients)
+    x = jnp.zeros((4, 16, 16, 3))
+
+    def loss(xx):
+        avg, stats = grouped_ensemble_logits(gspecs, gparams, xx,
+                                             with_bn_stats=True)
+        return jnp.sum(avg ** 2) + LS.bn_loss(stats)
+
+    text = jax.jit(jax.grad(loss)).lower(x).as_text()
+    assert ("stablehlo.while" in text) == loops
 
 
 def test_grouped_matches_under_jit_homogeneous():
